@@ -631,6 +631,8 @@ def main_lint(argv: List[str]) -> int:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     argv = sys.argv[1:] if argv is None else list(argv)
     if argv and argv[0] == "validate":
         return main_validate(argv[1:])

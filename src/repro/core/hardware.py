@@ -140,7 +140,23 @@ def scaled_die(hw: HW, scale: float) -> HW:
 
 
 # --- TPU v5e constants (assignment roofline; NOT the paper's hardware) ---
+# Google Cloud documentation, "TPU v5e"
 TPU_V5E_FLOPS = 197e12        # bf16 FLOP/s per chip
 TPU_V5E_HBM_BW = 819e9        # B/s
 TPU_V5E_ICI_BW = 50e9         # B/s per link
 TPU_V5E_HBM_GB = 16.0
+
+# per-chip peaks keyed by jax's ``Device.device_kind``
+CHIP_PEAKS = {
+    "TPU v5 lite": {"flops": TPU_V5E_FLOPS, "hbm_bw": TPU_V5E_HBM_BW,
+                    "ici_bw": TPU_V5E_ICI_BW, "hbm_gb": TPU_V5E_HBM_GB},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; an unknown kind raises."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(CHIP_PEAKS)}") from None

@@ -682,22 +682,11 @@ def _jax_terms_fn(fabric: str, hw: HW, w_scalars: Tuple):
     return jax.jit(jax.vmap(point_fn))
 
 
-@functools.lru_cache(maxsize=1)
-def _jax_available() -> bool:
-    try:
-        import jax                                   # noqa: F401
-        return True
-    except Exception:
-        return False
-
-
 def resolve_backend(backend: str, n_rows: int) -> str:
     """Map ``auto`` to a concrete backend for a batch of ``n_rows``."""
     if backend != "auto":
         return backend
-    if n_rows >= JAX_AUTO_MIN_BATCH and _jax_available():
-        return "jax"
-    return "numpy"
+    return "jax" if n_rows >= JAX_AUTO_MIN_BATCH else "numpy"
 
 
 def _bucket(n: int) -> int:
@@ -709,7 +698,7 @@ def _run_terms(a: Dict, fabric: str, hw: HW, backend: str):
     if backend == "numpy":
         return _terms_core(np, a, fabric, hw)
     if backend == "jax":
-        from jax.experimental import enable_x64
+        import jax
         fn = _jax_terms_fn(fabric, hw, a["w_scalars"])
         B = a["vols"].shape[0]
         pad = _bucket(B) - B
@@ -724,7 +713,7 @@ def _run_terms(a: Dict, fabric: str, hw: HW, backend: str):
                            ((0, pad),) + ((0, 0),) * (v.ndim - 1),
                            mode="edge")
             args.append(v)
-        with enable_x64():
+        with jax.enable_x64(True):
             out = fn(*args)
         return {k: np.asarray(v)[:B] for k, v in out.items()}
     raise ValueError(f"unknown backend {backend!r}")

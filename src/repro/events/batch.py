@@ -64,7 +64,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from repro.dse.batched_sim import _bucket, _jax_available
+from repro.dse.batched_sim import _bucket
 from repro.events.dag import StepProgram, device_op_order, op_dependency
 from repro.obs import metrics
 
@@ -98,8 +98,7 @@ def jax_stats() -> Dict[str, int]:
 def resolve_backend(backend: str, n_records: int) -> str:
     """Map ``auto`` to a concrete wavefront backend for K records."""
     if backend == "auto":
-        return "jax" if (n_records >= JAX_AUTO_MIN_RECORDS
-                         and _jax_available()) else "numpy"
+        return "jax" if n_records >= JAX_AUTO_MIN_RECORDS else "numpy"
     if backend not in ("numpy", "jax"):
         raise ValueError(f"unknown backend {backend!r}; "
                          f"use 'numpy', 'jax' or 'auto'")
@@ -358,11 +357,11 @@ def _replay_jax(shape_keys: Sequence[Tuple], key_rows: np.ndarray,
     (``key_rows`` maps row -> index into ``shape_keys``), one jit call
     per group, rows edge-padded to the next power-of-two bucket,
     scatter back."""
-    from jax.experimental import enable_x64
+    import jax
     K = rows.shape[1]
     n_keys = len(shape_keys)
     metrics.inc("batch_replay.jax_calls", n_keys)
-    with enable_x64():
+    with jax.enable_x64(True):
         if n_keys == 1:                 # fast path: no gather/scatter
             nb = _bucket(K)
             fn = _jax_shape_fn(*shape_keys[0])

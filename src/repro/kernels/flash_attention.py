@@ -20,11 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _compiler_params(**kw):
-    from repro.kernels.ops import tpu_compiler_params  # lazy: avoid cycle
-    return tpu_compiler_params(**kw)
-
 NEG_INF = -1e30
 
 
@@ -65,22 +60,21 @@ def _fa_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
             mask = jnp.logical_and(mask, cols <= rows)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_ref[...]                                 # (block_q, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * alpha
                         + jax.lax.dot(p.astype(v.dtype), v,
                                       preferred_element_type=jnp.float32))
         m_ref[...] = m_new
 
     @pl.when(ki == n_k - 1)
     def _finalize():
-        l = l_ref[...]
-        lse_ref[0, 0, :] = (m_ref[...] + jnp.log(jnp.where(l == 0.0, 1.0, l)))
-        l = jnp.where(l == 0.0, 1.0, l)   # fully-masked rows -> 0 output
-        o_ref[0, 0, ...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])  # masked rows -> 0
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
+        o_ref[0, 0, ...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -127,21 +121,24 @@ def flash_attention_fwd(q, k, v, window=None, *, causal=True, softcap=0.0,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda b_, h_, qi, ki: (b_, h_, qi)),
+            # lse as a (block_q, 1) column: a block's last two dims must
+            # tile (8, 128) or span the array, and a trailing 1 spans it
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(win, q, k, v)
-    return out  # (o, lse)
+    o, lse = out
+    return o, lse[..., 0]

@@ -19,10 +19,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _compiler_params(**kw):
-    from repro.kernels.ops import tpu_compiler_params  # lazy: avoid cycle
-    return tpu_compiler_params(**kw)
-
 
 def _gmm_kernel(gid_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k):
     ki = pl.program_id(2)
@@ -74,7 +70,7 @@ def moe_gmm(x, w, block_group_ids, *, block_t=128, block_n=128, block_k=128,
         functools.partial(_gmm_kernel, n_k=n_k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, n), x.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_group_ids.astype(jnp.int32), x, w)

@@ -18,23 +18,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-
-def tpu_compiler_params(**kwargs):
-    """Version-compat shim for the Pallas-TPU compiler-params class.
-
-    jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``;
-    resolve whichever this jax provides.  Kernels import this lazily
-    (inside the kernel entry point) so the ops<->kernel module cycle
-    stays one-directional at import time.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-from repro.kernels import ref as _ref  # noqa: E402
+from repro.kernels import ref as _ref
 from repro.kernels.flash_attention import flash_attention_fwd as _fa_pallas
 from repro.kernels.moe_gmm import moe_gmm as _gmm_pallas
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm_pallas
@@ -48,6 +34,35 @@ def default_backend() -> str:
     if env:
         return env
     return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+# ===========================================================================
+# Pallas kernels under a mesh
+# ===========================================================================
+# GSPMD cannot partition a Mosaic kernel, so under a mesh of more than one
+# device (``jax.set_mesh``) each Pallas call runs once per shard inside a
+# shard_map: the batch over every axis but ``model``, heads over ``model``,
+# each only where it divides.  Whatever does not divide is replicated.
+def _kernel_mesh():
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or mesh.size == 1 else mesh
+
+
+def _mesh_axes(mesh, batch: int, heads: int):
+    """(batch axes, head axis) of a kernel call; None where not sharded."""
+    data = tuple(a for a in mesh.axis_names if a != "model")
+    dsize = 1
+    for a in data:
+        dsize *= mesh.shape[a]
+    b_ax = data if data and batch % dsize == 0 else None
+    msize = mesh.shape.get("model", 1)
+    h_ax = "model" if msize > 1 and heads % msize == 0 else None
+    return b_ax, h_ax
+
+
+def _per_shard(fn, mesh, in_specs, out_specs, *args):
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ===========================================================================
@@ -308,14 +323,31 @@ def _static_window(window):
 def _flash_attention_fwd_rule(q, k, v, window, causal, softcap, scale,
                               block, backend):
     if backend == "pallas":
-        o, lse = _fa_pallas(q, k, v, window, causal=causal, softcap=softcap,
-                            scale=scale, block_q=block, block_k=block)
+        o, lse = _fa_pallas_on_mesh(q, k, v, window, causal, softcap,
+                                    scale, block)
     elif backend == "xla_blocked" and _static_window(window):
         o, lse = _fa_fwd_xla_blocked(q, k, v, window, causal, softcap,
                                      scale, block)
     else:
         o, lse = _fa_fwd_xla(q, k, v, window, causal, softcap, scale, block)
     return o, (q, k, v, o, lse, window)
+
+
+def _fa_pallas_on_mesh(q, k, v, window, causal, softcap, scale, block):
+    call = functools.partial(_fa_pallas, causal=causal, softcap=softcap,
+                             scale=scale, block_q=block, block_k=block)
+    mesh = _kernel_mesh()
+    if mesh is None:
+        return call(q, k, v, window)
+    b_ax, h_ax = _mesh_axes(mesh, q.shape[0],
+                            math_gcd(q.shape[1], k.shape[1]))
+    qs = P(b_ax, h_ax, None, None)
+    out_specs = (qs, P(b_ax, h_ax, None))
+    if _static_window(window):
+        return _per_shard(lambda q_, k_, v_: call(q_, k_, v_, window), mesh,
+                          (qs, qs, qs), out_specs, q, k, v)
+    return _per_shard(call, mesh, (qs, qs, qs, P()), out_specs,
+                      q, k, v, jnp.asarray(window, jnp.int32))
 
 
 def _flash_attention_bwd_rule(causal, softcap, scale, block, backend, res,
@@ -408,10 +440,27 @@ def moe_gmm(x, w, group_sizes_or_blockids, *, backend=None, block_t=128):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _ssd(x, dt, A, B, C, chunk, backend):
     if backend == "pallas":
-        return _ssd_pallas(x, dt, A, B, C, chunk=chunk)
+        return _ssd_pallas_on_mesh(x, dt, A, B, C, chunk)
     unroll = backend == "xla_blocked"
     y, _ = _ref.ssd_chunked_ref(x, dt, A, B, C, chunk=chunk, unroll=unroll)
     return y
+
+
+def _ssd_pallas_on_mesh(x, dt, A, B, C, chunk):
+    call = functools.partial(_ssd_pallas, chunk=chunk)
+    mesh = _kernel_mesh()
+    if mesh is None:
+        return call(x, dt, A, B, C)
+    h, g = x.shape[2], B.shape[2]
+    b_ax, h_ax = _mesh_axes(mesh, x.shape[0], h)
+    # B/C groups shard with the heads, or are replicated when there is one
+    g_ax = h_ax if h_ax and g % mesh.shape["model"] == 0 else None
+    if g_ax is None and g != 1:
+        h_ax = None
+    xs = P(b_ax, None, h_ax, None)
+    gs = P(b_ax, None, g_ax, None)
+    return _per_shard(call, mesh, (xs, P(b_ax, None, h_ax), P(h_ax), gs, gs),
+                      xs, x, dt, A, B, C)
 
 
 def _ssd_fwd(x, dt, A, B, C, chunk, backend):
@@ -449,8 +498,14 @@ def rmsnorm(x, w, *, eps=1e-6, weight_offset=0.0, backend=None):
         # fwd-only pallas; bwd recomputes via the jnp formulation
         @jax.custom_vjp
         def _rn(x_, w_):
-            return _rmsnorm_pallas(x_, w_, eps=eps,
-                                   weight_offset=weight_offset)
+            call = functools.partial(_rmsnorm_pallas, eps=eps,
+                                     weight_offset=weight_offset)
+            mesh = _kernel_mesh()
+            if mesh is None:
+                return call(x_, w_)
+            b_ax, _ = _mesh_axes(mesh, x_.shape[0], 1)
+            xs = P(b_ax, *([None] * (x_.ndim - 1)))
+            return _per_shard(call, mesh, (xs, P(None)), xs, x_, w_)
 
         def _rn_fwd(x_, w_):
             return _rn(x_, w_), (x_, w_)

@@ -9,10 +9,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _compiler_params(**kw):
-    from repro.kernels.ops import tpu_compiler_params  # lazy: avoid cycle
-    return tpu_compiler_params(**kw)
-
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps, weight_offset):
     x = x_ref[...].astype(jnp.float32)
@@ -48,7 +44,7 @@ def rmsnorm(x, w, *, eps=1e-6, weight_offset=0.0, block_rows=256,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, w)

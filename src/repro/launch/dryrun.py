@@ -155,7 +155,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         batch_shape = model.input_specs(shape, ex, kind="train")
         bs = batch_specs(cfg, shape, mesh, kind="train")
         batch_sh = {k: NamedSharding(mesh, bs(k)) for k in batch_shape}
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=(state_sh, batch_sh)
                               ).lower(state_shape, batch_shape)
     elif shape.kind == "prefill":
@@ -163,7 +163,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         batch_shape = model.input_specs(shape, ex, kind="prefill")
         bs = batch_specs(cfg, shape, mesh, kind="prefill")
         batch_sh = {k: NamedSharding(mesh, bs(k)) for k in batch_shape}
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=(p_sh, batch_sh)
                               ).lower(params_shape, batch_shape)
     else:  # decode
@@ -181,7 +181,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
             mesh, P(fsdp) if shape.global_batch % fsdp_size == 0
             else P())
         pos_sh = NamedSharding(mesh, P())
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=(
                 p_sh, cache_sh, tok_sh, pos_sh)).lower(
                     params_shape, specs["cache"], specs["tokens"],

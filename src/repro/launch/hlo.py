@@ -20,7 +20,7 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict
 
-import numpy as np
+from repro.core.hardware import chip_peaks
 
 DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -108,19 +108,19 @@ def parse_collectives(hlo_text: str) -> CollectiveStats:
 
 
 # ---------------------------------------------------------------------------
-# Roofline terms (TPU v5e per the assignment)
+# Roofline terms
 # ---------------------------------------------------------------------------
-def roofline_terms(flops: float, hbm_bytes: float, wire_bytes: float,
-                   n_chips: int, *, peak_flops=197e12, hbm_bw=819e9,
-                   link_bw=50e9) -> Dict[str, float]:
-    """All three terms in SECONDS (cluster-level work / cluster capacity).
+def roofline_terms(flops: float, hbm_bytes: float, wire_bytes: float, *,
+                   device_kind: str) -> Dict[str, float]:
+    """All three terms in SECONDS against one chip of ``device_kind``
+    (``core.hardware.CHIP_PEAKS``; an unknown kind raises).
 
-    flops/hbm_bytes from cost_analysis are per-program (already per-device
-    under SPMD? No — cost_analysis of an SPMD module reports the PER-DEVICE
-    program).  wire_bytes likewise per-device.  So divide by per-chip peak.
+    cost_analysis of an SPMD module reports the PER-DEVICE program, and
+    wire_bytes are per device too, so each divides by a per-chip peak.
     """
+    pk = chip_peaks(device_kind)
     return {
-        "compute_s": flops / peak_flops,
-        "memory_s": hbm_bytes / hbm_bw,
-        "collective_s": wire_bytes / link_bw,
+        "compute_s": flops / pk["flops"],
+        "memory_s": hbm_bytes / pk["hbm_bw"],
+        "collective_s": wire_bytes / pk["ici_bw"],
     }

@@ -7,6 +7,13 @@ the dry-run must set XLA_FLAGS before any jax initialisation.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(axes) -> tuple:
+    """GSPMD-propagated (Auto) axis types: the sharding rules in
+    parallel/sharding.py constrain only inputs and a few activations."""
+    return (AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +26,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     need = math.prod(shape)
     devs = jax.devices()
     if len(devs) == need:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, axis_types=_auto(axes))
     if len(devs) < need:
         raise RuntimeError(
             f"mesh {shape} needs {need} devices, have {len(devs)} — run "
@@ -36,9 +43,14 @@ def model_axis(mesh) -> str:
     return "model"
 
 
-def make_mesh_from_plan(tp: int, dp: int, *, pod: int = 1):
+def make_mesh_from_plan(tp: int, dp: int, *, pod: int = 1, devices=None):
     """Build a mesh realising a ChipLight ``ParallelPlan``'s TP x DP grid
-    (EP/CP ride the data axis, see parallel/plan.py)."""
+    (EP/CP ride the data axis, see parallel/plan.py) over ``devices``
+    (default: all of them)."""
     if pod > 1:
-        return jax.make_mesh((pod, dp, tp), ("pod", "data", "model"))
-    return jax.make_mesh((dp, tp), ("data", "model"))
+        axes = ("pod", "data", "model")
+        return jax.make_mesh((pod, dp, tp), axes, axis_types=_auto(axes),
+                             devices=devices)
+    axes = ("data", "model")
+    return jax.make_mesh((dp, tp), axes, axis_types=_auto(axes),
+                         devices=devices)

@@ -20,15 +20,17 @@ from repro.configs import SHAPES, get_config
 from repro.configs.base import ShapeConfig
 from repro.data import DataPipeline
 from repro.checkpoint import CheckpointManager
+from repro.launch.mesh import make_mesh_from_plan
 from repro.launch.steps import TrainState, init_train_state, \
     make_train_step
 from repro.models.common import ExecConfig
 from repro.optim import AdamWState
-from repro.parallel.sharding import batch_specs, param_specs
+from repro.parallel.sharding import param_specs
 from repro.runtime import FaultTolerantLoop
+from repro.runtime.compile_cache import use_compile_cache
 
 
-def build_sharded_train(cfg, ex, mesh, shape, accum=1, base_lr=3e-4):
+def build_sharded_train(cfg, ex, mesh, accum=1, base_lr=3e-4):
     step_fn = make_train_step(cfg, ex, base_lr=base_lr, accum=accum)
     params_shape = jax.eval_shape(
         lambda: init_train_state(cfg, ex).params)
@@ -39,9 +41,10 @@ def build_sharded_train(cfg, ex, mesh, shape, accum=1, base_lr=3e-4):
         params=p_sh,
         opt=AdamWState(step=NamedSharding(mesh, P()),
                        m=p_sh, v=p_sh))
-    bs = batch_specs(cfg, shape, mesh, kind="train")
+    # the state comes back on ``state_sh`` too, so the donated state of
+    # step n is a valid input of step n + 1
     jitted = jax.jit(step_fn, in_shardings=(state_sh, None),
-                     donate_argnums=(0,))
+                     out_shardings=(state_sh, None), donate_argnums=(0,))
     return jitted, state_sh
 
 
@@ -67,15 +70,15 @@ def main(argv=None):
     shape = ShapeConfig("cli", "train", args.seq, args.batch)
     ex = ExecConfig(ssd_chunk=min(64, args.seq), attn_block=128)
 
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model")) if n_dev > 1 \
-        else jax.make_mesh((1, 1), ("data", "model"))
+    use_compile_cache()
+    mesh = make_mesh_from_plan(tp=1, dp=len(jax.devices()))
 
-    with mesh:
-        step_fn, state_sh = build_sharded_train(cfg, ex, mesh, shape,
+    with jax.set_mesh(mesh):
+        step_fn, state_sh = build_sharded_train(cfg, ex, mesh,
                                                 accum=args.accum,
                                                 base_lr=args.lr)
-        state = init_train_state(cfg, ex, seed=args.seed)
+        state = jax.device_put(init_train_state(cfg, ex, seed=args.seed),
+                               state_sh)
         pipeline = DataPipeline(cfg, shape, seed=args.seed, ex=ex)
         ckpt = CheckpointManager(args.ckpt_dir)
         loop = FaultTolerantLoop(step_fn, ckpt, pipeline,

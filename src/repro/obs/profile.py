@@ -16,12 +16,12 @@ samples the achieved rates onto the installed tracer as
 ``cli calibrate --trace`` renders the whole grid as a Perfetto timeline
 with counter tracks over it.
 
-On CPU the harness exercises the xla (blockwise-jnp) kernel path: the
-absolute rates are host numbers, but they saturate with M exactly like
-the accelerator curves — which is what the fit extracts.  On a TPU host
-``default_backend()`` selects the Pallas kernels and the same harness
-measures those.  jax and the kernel package are imported lazily so
-``repro.obs`` itself stays import-light.
+Each case times the kernels ``ops.default_backend()`` picks — the
+Pallas kernels on a TPU, the xla (blockwise-jnp) path elsewhere — and
+every row records that ``backend``.  On a CPU the absolute rates are
+host numbers, but they saturate with M like the accelerator curves,
+which is what the fit extracts.  jax and the kernel package are
+imported lazily so ``repro.obs`` itself stays import-light.
 """
 from __future__ import annotations
 
@@ -89,8 +89,7 @@ def _fa_case(s: int, bwd: bool):
     block = min(128, s)
 
     def fwd(q_, k_, v_):
-        return ops.flash_attention(q_, k_, v_, causal=True, block=block,
-                                   backend="xla")
+        return ops.flash_attention(q_, k_, v_, causal=True, block=block)
 
     if bwd:
         # fwd + bwd in one call (the custom-VJP recompute path): the
@@ -111,13 +110,19 @@ def _moe_case(t: int, n: int):
 
     from repro.kernels import ops
     e, k = 4, 256
-    sizes = [t // e] * e
-    sizes[0] += t - sum(sizes)
     ks = jax.random.split(jax.random.PRNGKey(1), 2)
     x = jax.random.normal(ks[0], (t, k), jnp.float32)
     w = jax.random.normal(ks[1], (e, k, n), jnp.float32) * 0.1
-    # group sizes are static (the xla/ref path requires concrete sizes)
-    fn = jax.jit(lambda x_, w_: ops.moe_gmm(x_, w_, sizes, backend="xla"))
+    # equal groups; every M on the grid is a multiple of 4 * 8, so each
+    # group is whole token blocks of the Pallas kernel
+    block_t = min(128, t // e)
+    if ops.default_backend() == "pallas":
+        groups = jnp.repeat(jnp.arange(e, dtype=jnp.int32),
+                            t // e // block_t)
+    else:           # the xla/ref path takes concrete group sizes
+        groups = [t // e] * e
+    fn = jax.jit(lambda x_, w_: ops.moe_gmm(x_, w_, groups,
+                                            block_t=block_t))
     flops = 2.0 * t * k * n
     bytes_ = _F32 * (t * k + e * k * n + t * n)
     return fn, (x, w), flops, bytes_, {"t": t, "e": e, "k": k, "n": n}
@@ -136,7 +141,7 @@ def _ssd_case(s: int):
     a = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.5)
     bm = jax.random.normal(ks[3], (b, s, g, n)) * 0.3
     cm = jax.random.normal(ks[4], (b, s, g, n)) * 0.3
-    fn = jax.jit(lambda *t: ops.ssd(*t, chunk=chunk, backend="xla"))
+    fn = jax.jit(lambda *t: ops.ssd(*t, chunk=chunk))
     # order-of-magnitude analytic count (state outer products + intra-
     # chunk attention-like term); only this kernel's own curve uses it
     flops = b * s * h * (6.0 * p * n + 2.0 * chunk * p)
@@ -153,7 +158,7 @@ def _rmsnorm_case(rows: int):
     d = 1024
     x = jax.random.normal(jax.random.PRNGKey(3), (rows, d), jnp.float32)
     w = jnp.ones((d,), jnp.float32)
-    fn = jax.jit(lambda x_, w_: ops.rmsnorm(x_, w_, backend="xla"))
+    fn = jax.jit(lambda x_, w_: ops.rmsnorm(x_, w_))
     flops = 4.0 * rows * d
     bytes_ = _F32 * (2.0 * rows * d + d)
     return fn, (x, w), flops, bytes_, {"rows": rows, "d": d}
@@ -208,12 +213,15 @@ def profile_kernels(kernels: Optional[Sequence[str]] = None, *,
                     reps: Optional[int] = None) -> List[dict]:
     """Measure every requested kernel over its (M, N) grid.
 
-    Returns one measurement dict per grid point: ``{kernel, kind, axis,
-    x, shape, flops, bytes, time_s, flops_per_s, bytes_per_s, reps}``.
+    Returns one measurement dict per grid point: ``{kernel, kind,
+    backend, axis, x, shape, flops, bytes, time_s, flops_per_s,
+    bytes_per_s, reps}``.
     Timing is best-of-``reps`` after a warm-up call (jit compile), via
     ``obs.bench.time_fn``.
     """
+    from repro.kernels.ops import default_backend
     from repro.obs.bench import time_fn
+    backend = default_backend()
     names = tuple(kernels) if kernels else PROFILE_KERNELS
     bad = sorted(set(names) - set(PROFILE_KERNELS))
     if bad:
@@ -229,8 +237,8 @@ def profile_kernels(kernels: Optional[Sequence[str]] = None, *,
                 with span("profile.measure", kernel=name, axis=axis,
                           x=x, reps=reps):
                     t = time_fn(fn, *args, reps=reps, warmup=1)
-                m = {"kernel": name, "kind": kind, "axis": axis,
-                     "x": int(x), "shape": shape, "flops": flops,
+                m = {"kernel": name, "kind": kind, "backend": backend,
+                     "axis": axis, "x": int(x), "shape": shape, "flops": flops,
                      "bytes": bytes_, "time_s": t,
                      "flops_per_s": flops / t, "bytes_per_s": bytes_ / t,
                      "reps": reps}

@@ -19,7 +19,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import MoEConfig
 from repro.models.moe import router_topk
@@ -106,11 +105,11 @@ def moe_apply_a2a(params, x, m: MoEConfig, ex, mesh):
 
     x_spec = P(data_axes if len(data_axes) > 1 else data_axes[0],
                "model", None)
-    out = shard_map(
+    out = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(x_spec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w1"], params["w3"], params["w2"])
     return out

@@ -14,7 +14,8 @@ if "XLA_FLAGS" not in os.environ and "jax" not in sys.modules:
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, NamedSharding, \
+    PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
 from repro.configs.base import ShapeConfig  # noqa: E402
@@ -33,7 +34,8 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh():
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def test_param_specs_cover_tree_and_divide():
@@ -82,7 +84,7 @@ def test_sharded_train_step_runs_tiny():
                                          m=p_sh, v=p_sh))
     shape = ShapeConfig("t", "train", 32, 4)
     batch = model.make_batch(jax.random.PRNGKey(0), shape, ex, "train")
-    with mesh:
+    with jax.set_mesh(mesh):
         state = jax.device_put(state, state_sh)
         jitted = jax.jit(step, in_shardings=(state_sh, None))
         new_state, metrics = jitted(state, batch)
