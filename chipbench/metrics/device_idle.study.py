@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the chip,
+from the profiler's device plane: 100 * (1 - busy / window)."""
+
+
+def read(run):
+    d = run.device
+    if not d or d["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - d["busy_s"] / d["window_s"])
