@@ -189,21 +189,24 @@ def _run_batched(sc: Scenario, driver: str,
     from repro.dse.search import (refine_sweep_rows, refine_top_points,
                                   sweep_design_space)
     t0 = time.perf_counter()
-    space = sc.design_space(alloc_mode=alloc_mode)
-    kw = _batched_driver_kw(sc, driver) if alloc_mode == "chiplight" \
-        else {}
+    with span("study.space"):
+        space = sc.design_space(alloc_mode=alloc_mode)
+        kw = _batched_driver_kw(sc, driver) if alloc_mode == "chiplight" \
+            else {}
     with span("study.scan", driver=driver):
         sweep = sweep_design_space(space, driver=driver,
                                    backend=sc.backend, seed=sc.seed, **kw)
-    kept = _sweep_keep_indices(sweep, sc)
+    with span("study.keep", rows=len(sweep)):
+        kept = _sweep_keep_indices(sweep, sc)
     # the event engine replicates the chiplight link allocation — the
     # railx sweep's analytic rows answer a different alloc, so the
     # schedule re-rank only runs on the chiplight path
     rerank = None
     if alloc_mode == "chiplight":
         kept, rerank = _event_rerank_stage(sc, sweep, kept)
-    records = records_from_sweep(sweep, kept)
-    rerank_prov = _stamp_rerank(records, rerank) if rerank else None
+    with span("study.records", rows=len(kept)):
+        records = records_from_sweep(sweep, kept)
+        rerank_prov = _stamp_rerank(records, rerank) if rerank else None
     t1 = time.perf_counter()
     points = []
     if sc.refine_top and len(kept):
@@ -214,43 +217,44 @@ def _run_batched(sc: Scenario, driver: str,
                 points = refine_sweep_rows(sweep, kept[: sc.refine_top])
             else:
                 points = refine_top_points(sweep, top_k=sc.refine_top)
-    refined = [record_from_point(p) for p in points]
-    if rerank_prov and refined:
-        # carry the winning (schedule, v) onto the refined duplicates
-        ev_by_key = {_record_key(r): {k: r.metrics[k]
-                                      for k in _EVENT_KEYS
-                                      if k in r.metrics}
-                     for r in records}
-        for r in refined:
-            r.metrics.update(ev_by_key.get(_record_key(r), {}))
-    records += refined
-    t2 = time.perf_counter()
+    with span("study.records", rows=len(points)):
+        refined = [record_from_point(p) for p in points]
+        if rerank_prov and refined:
+            # carry the winning (schedule, v) onto the refined duplicates
+            ev_by_key = {_record_key(r): {k: r.metrics[k]
+                                          for k in _EVENT_KEYS
+                                          if k in r.metrics}
+                         for r in records}
+            for r in refined:
+                r.metrics.update(ev_by_key.get(_record_key(r), {}))
+        records += refined
+        t2 = time.perf_counter()
 
-    best: Optional[int] = None
-    if points:                       # refined best-first (exact costs)
-        best = len(records) - len(points)
-    elif records:
-        best = 0                     # kept rows are best-first
-    timings = {"sweep_s": sweep.elapsed_s,
-               "refine_s": t2 - t1, "total_s": t2 - t0}
-    if rerank is not None:
-        timings["rerank_s"] = rerank["elapsed_s"]
-    result = StudyResult(
-        scenario=sc, records=records, best=best, points=points,
-        traces=[],
-        timings=timings,
-        provenance=_provenance(sc,
-                               engine=engine
-                               or f"dse.sweep[{driver}]+refine",
-                               grid_evaluated=len(sweep),
-                               n_sim=int(sweep.n_sim),
-                               n_cache_hits=int(sweep.n_cache_hits),
-                               n_feasible=int(sweep.metrics["feasible"]
-                                              .sum()),
-                               n_kept=len(kept), n_refined=len(points)))
-    if rerank_prov is not None:
-        result.provenance["event_rerank"] = rerank_prov
-    result.pareto = result.pareto_indices()
+        best: Optional[int] = None
+        if points:                   # refined best-first (exact costs)
+            best = len(records) - len(points)
+        elif records:
+            best = 0                 # kept rows are best-first
+        timings = {"sweep_s": sweep.elapsed_s,
+                   "refine_s": t2 - t1, "total_s": t2 - t0}
+        if rerank is not None:
+            timings["rerank_s"] = rerank["elapsed_s"]
+        result = StudyResult(
+            scenario=sc, records=records, best=best, points=points,
+            traces=[],
+            timings=timings,
+            provenance=_provenance(sc,
+                                   engine=engine
+                                   or f"dse.sweep[{driver}]+refine",
+                                   grid_evaluated=len(sweep),
+                                   n_sim=int(sweep.n_sim),
+                                   n_cache_hits=int(sweep.n_cache_hits),
+                                   n_feasible=int(sweep.metrics["feasible"]
+                                                  .sum()),
+                                   n_kept=len(kept), n_refined=len(points)))
+        if rerank_prov is not None:
+            result.provenance["event_rerank"] = rerank_prov
+        result.pareto = result.pareto_indices()
     return result
 
 

@@ -38,6 +38,7 @@ from repro.core.mcm import MCMArch
 from repro.core.workload import Workload
 from repro.dse.space import P_IDX, StrategyBatch
 from repro.obs import metrics as obs_metrics
+from repro.obs import span
 
 
 @dataclass(frozen=True)
@@ -701,21 +702,32 @@ def _run_terms(a: Dict, fabric: str, hw: HW, backend: str):
         import jax
         fn = _jax_terms_fn(fabric, hw, a["w_scalars"])
         B = a["vols"].shape[0]
-        pad = _bucket(B) - B
+        bucket = _bucket(B)
+        pad = bucket - B
         obs_metrics.inc("batched_sim.jax_calls")
+        obs_metrics.inc("batched_sim.jax_rows", B)
         obs_metrics.inc("batched_sim.jax_pad_rows", pad)
-        obs_metrics.gauge("batched_sim.jax_bucket", _bucket(B))
-        args = []
-        for k in _TERM_KEYS:
-            v = np.asarray(a[k])
-            if pad:                     # edge rows: real values, so the
-                v = np.pad(v,           # padded tail stays finite
-                           ((0, pad),) + ((0, 0),) * (v.ndim - 1),
-                           mode="edge")
-            args.append(v)
-        with jax.enable_x64(True):
-            out = fn(*args)
-        return {k: np.asarray(v)[:B] for k, v in out.items()}
+        with span("sim.pad", rows=B, bucket=bucket):
+            args = []
+            for k in _TERM_KEYS:
+                v = np.asarray(a[k])
+                if pad:                 # edge rows: real values, so the
+                    v = np.pad(v,       # padded tail stays finite
+                               ((0, pad),) + ((0, 0),) * (v.ndim - 1),
+                               mode="edge")
+                args.append(v)
+        obs_metrics.inc("batched_sim.h2d_bytes",
+                        sum(v.nbytes for v in args))
+        traces0 = _JAX_TRACES["count"]
+        # transfer in, run, transfer out: np.asarray waits for the chip
+        with span("sim.device", fabric=fabric, rows=B,
+                  bucket=bucket) as sp:
+            with jax.enable_x64(True):
+                out = {k: np.asarray(v) for k, v in fn(*args).items()}
+            sp.set(retraced=_JAX_TRACES["count"] > traces0)
+        obs_metrics.inc("batched_sim.d2h_bytes",
+                        sum(v.nbytes for v in out.values()))
+        return {k: v[:B] for k, v in out.items()}
     raise ValueError(f"unknown backend {backend!r}")
 
 

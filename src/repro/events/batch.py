@@ -366,7 +366,6 @@ def _replay_jax(shape_keys: Sequence[Tuple], key_rows: np.ndarray,
             nb = _bucket(K)
             fn = _jax_shape_fn(*shape_keys[0])
             metrics.inc("batch_replay.jax_pad_rows", nb - K)
-            metrics.gauge("batch_replay.jax_bucket", nb)
             return np.asarray(fn(_pad_edge(rows, nb)))[:, :K]
         out = np.empty((len(_RES_KEYS), K))
         for ki in range(n_keys):
@@ -375,7 +374,6 @@ def _replay_jax(shape_keys: Sequence[Tuple], key_rows: np.ndarray,
             nb = _bucket(n)
             fn = _jax_shape_fn(*shape_keys[ki])
             metrics.inc("batch_replay.jax_pad_rows", nb - n)
-            metrics.gauge("batch_replay.jax_bucket", nb)
             out[:, idx] = np.asarray(fn(_pad_edge(rows[:, idx], nb)))[:, :n]
     return out
 

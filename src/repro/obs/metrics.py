@@ -2,7 +2,7 @@
 
 Counters are monotonically increasing event counts
 (``batch_replay.scalar_fallback``, ``dse.cache.hits``); gauges are
-last-write-wins levels (``batched_sim.jax_bucket``).  Names are dotted
+last-write-wins levels (``profile.achieved_tflops``).  Names are dotted
 ``<subsystem>.<noun>[.<qualifier>]`` — see DESIGN.md §observability for
 the naming discipline.
 
@@ -44,9 +44,12 @@ KNOWN_COUNTERS = frozenset({
     "batch_replay.jax_retraces",
     "batch_replay.records",
     "batch_replay.scalar_fallback",
+    "batched_sim.d2h_bytes",
+    "batched_sim.h2d_bytes",
     "batched_sim.jax_calls",
     "batched_sim.jax_pad_rows",
     "batched_sim.jax_retraces",
+    "batched_sim.jax_rows",
     "compile_batch.records",
     "dse.cache.fallback_rows",
     "dse.cache.hits",
@@ -58,8 +61,6 @@ KNOWN_COUNTERS = frozenset({
     "profile.measurements",
 })
 KNOWN_GAUGES = frozenset({
-    "batch_replay.jax_bucket",
-    "batched_sim.jax_bucket",
     "profile.achieved_gbs",
     "profile.achieved_tflops",
 })

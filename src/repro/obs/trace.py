@@ -1,12 +1,22 @@
 """Contextvar-scoped tracing spans — the host-side flight recorder.
 
 A ``Tracer`` collects COMPLETED spans: every ``with span("name", k=v):``
-block appends one ``{name, ts_ns, dur_ns, depth, args}`` record when it
-exits, timestamped with ``time.perf_counter_ns`` relative to the
-tracer's birth.  Spans nest lexically and are LIFO-checked — closing a
-span that is not the innermost open one raises, as does a clock that
-runs backwards, so a trace that exports cleanly is structurally sound
-by construction.
+block appends one ``{name, ts_ns, dur_ns, depth, id, parent, args}``
+record when it exits, timestamped with ``time.perf_counter_ns`` relative
+to the tracer's birth.  ``id`` is unique within the tracer (numbered in
+the order spans open); ``parent`` is the ``id`` of the span that was
+innermost when this one opened (``None`` at a root), so the call tree
+rebuilds exactly from the records.  Spans nest lexically and are
+LIFO-checked — closing a span that is not the innermost open one raises,
+as does a clock that runs backwards, so a trace that exports cleanly is
+structurally sound by construction.
+
+While a tracer is installed and ``jax`` has already been imported by
+someone else, each span also enters a ``jax.profiler.TraceAnnotation``
+of the same name (args as its metadata, which jax encodes only while
+the profiler records).  A ``jax.profiler`` capture then shows the
+program's stages on its host plane, on the same clock as the chip's
+operations.  This module never imports jax itself.
 
 The layer is built to be left in hot loops permanently: when no tracer
 is installed (the default), ``span()`` returns a module-level no-op
@@ -26,6 +36,7 @@ see their own tracer, and library code never needs a tracer argument.
 """
 from __future__ import annotations
 
+import sys
 import time
 from contextlib import contextmanager
 from contextvars import ContextVar
@@ -45,6 +56,7 @@ class Tracer:
         # exported as Chrome-trace "C" counter tracks
         self.counter_samples: List[Tuple[str, int, float]] = []
         self._stack: List["_Span"] = []
+        self._next_id = 0
 
     def now_ns(self) -> int:
         return time.perf_counter_ns() - self.t0_ns
@@ -57,10 +69,19 @@ class Tracer:
         return len(self._stack)
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` if jax is already imported, else
+    None (looked up, never imported: the numpy-only paths stay free of
+    jax)."""
+    prof = getattr(sys.modules.get("jax"), "profiler", None)
+    return getattr(prof, "TraceAnnotation", None)
+
+
 class _Span:
     """Live span; records itself on the owning tracer at ``__exit__``."""
 
-    __slots__ = ("tracer", "name", "args", "start_ns", "_depth")
+    __slots__ = ("tracer", "name", "args", "start_ns", "_depth", "_id",
+                 "_parent", "_ann")
 
     def __init__(self, tracer: Tracer, name: str,
                  args: Optional[Dict[str, Any]]):
@@ -69,11 +90,29 @@ class _Span:
         self.args = args
         self.start_ns = 0
         self._depth = 0
+        self._id = 0
+        self._parent: Optional[int] = None
+        self._ann = None
+
+    def set(self, **args: Any) -> None:
+        """Add args known only inside the span (e.g. whether a call
+        compiled); they go on the record and the profiler annotation."""
+        self.args = {**(self.args or {}), **args}
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __enter__(self) -> "_Span":
         tr = self.tracer
-        self._depth = len(tr._stack)
-        tr._stack.append(self)
+        stack = tr._stack
+        self._depth = len(stack)
+        self._parent = stack[-1]._id if stack else None
+        self._id = tr._next_id
+        tr._next_id += 1
+        stack.append(self)
+        ann = _profiler_annotation()
+        if ann is not None:
+            self._ann = ann(self.name, **(self.args or {}))
+            self._ann.__enter__()
         self.start_ns = tr.now_ns()
         return self
 
@@ -86,13 +125,16 @@ class _Span:
                 f"(innermost open span: {open_name!r})")
         tr._stack.pop()
         end_ns = tr.now_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if end_ns < self.start_ns:
             raise RuntimeError(
                 f"span {self.name!r}: end {end_ns} < start "
                 f"{self.start_ns} — non-monotonic clock")
         tr.events.append({"name": self.name, "ts_ns": self.start_ns,
                           "dur_ns": end_ns - self.start_ns,
-                          "depth": self._depth, "args": self.args})
+                          "depth": self._depth, "id": self._id,
+                          "parent": self._parent, "args": self.args})
         return False
 
 
@@ -100,6 +142,9 @@ class _NullSpan:
     """Zero-cost stand-in handed out when tracing is disabled."""
 
     __slots__ = ()
+
+    def set(self, **args: Any) -> None:
+        pass
 
     def __enter__(self) -> "_NullSpan":
         return self

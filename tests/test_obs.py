@@ -73,6 +73,49 @@ def test_span_nesting_depths_and_order():
     assert all(e["dur_ns"] >= 0 for e in tr.events)
 
 
+def test_span_records_rebuild_the_call_tree():
+    with tracing() as tr:
+        with span("root"):
+            with span("a"):
+                with span("a1"):
+                    pass
+                with span("a2"):
+                    pass
+            with span("b"):
+                pass
+        with span("root2"):
+            pass
+    ids = [e["id"] for e in tr.events]
+    assert len(set(ids)) == len(ids)
+    name = {e["id"]: e["name"] for e in tr.events}
+    tree = {}
+    for e in tr.events:
+        parent = None if e["parent"] is None else name[e["parent"]]
+        tree.setdefault(parent, []).append(e["name"])
+    assert {k: sorted(v) for k, v in tree.items()} == {
+        None: ["root", "root2"], "root": ["a", "b"], "a": ["a1", "a2"]}
+    # ids number spans in the order they opened; depth is the tree's
+    opened = [name[i] for i in sorted(ids)]
+    assert opened == ["root", "a", "a1", "a2", "b", "root2"]
+    by = {e["name"]: e for e in tr.events}
+    for e in tr.events:
+        want = 0 if e["parent"] is None else \
+            by[name[e["parent"]]]["depth"] + 1
+        assert e["depth"] == want
+
+
+def test_span_set_adds_args():
+    with tracing() as tr:
+        with span("call", rows=3) as sp:
+            sp.set(retraced=True)
+        with span("bare") as sp:
+            sp.set(k=1)
+    by = {e["name"]: e for e in tr.events}
+    assert by["call"]["args"] == {"rows": 3, "retraced": True}
+    assert by["bare"]["args"] == {"k": 1}
+    span("off").set(k=1)                   # the disabled span ignores it
+
+
 def test_span_lifo_violation_raises():
     tr = Tracer()
     with tracing(tr):
@@ -252,7 +295,125 @@ def test_study_traced_emits_stage_spans():
         Study(_tiny_scenario()).run()
     names = {e["name"] for e in tr.events}
     assert {"study.run", "study.scan", "study.refine",
-            "sweep", "refine"} <= names
+            "sweep", "refine", "study.space", "study.keep",
+            "study.records"} <= names
+
+
+def test_study_run_children_cover_its_stages():
+    """An exhaustive study's own work is all inside named stages: the
+    direct children of ``study.run`` are exactly these, in this order,
+    and together take no longer than the run."""
+    from repro.api import Study
+    with tracing() as tr:
+        Study(_tiny_scenario()).run()
+    run, = [e for e in tr.events if e["name"] == "study.run"]
+    kids = sorted((e for e in tr.events if e["parent"] == run["id"]),
+                  key=lambda e: e["ts_ns"])
+    assert [e["name"] for e in kids] == [
+        "study.space", "study.scan", "study.keep", "study.records",
+        "study.refine", "study.records"]
+    assert sum(e["dur_ns"] for e in kids) <= run["dur_ns"]
+    assert all(e["depth"] == run["depth"] + 1 for e in kids)
+
+
+def _jax_study_traced(monkeypatch, **kw):
+    """A study on ``backend="jax"`` (CPU) under a tracer and a metrics
+    scope; also returns the arguments each device call received."""
+    pytest.importorskip("jax")
+    from repro.api import Study
+    from repro.dse import batched_sim
+    sent = []
+    real = batched_sim._jax_terms_fn
+
+    def spy(*key):
+        fn = real(*key)
+
+        def call(*args):
+            sent.append(args)
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(batched_sim, "_jax_terms_fn", spy)
+    with tracing() as tr, metrics.scope() as m:
+        Study(_tiny_scenario(backend="jax").replace(**kw)).run()
+    return tr, m, sent
+
+
+def test_jax_scan_spans_and_transfer_counters(monkeypatch):
+    tr, m, sent = _jax_study_traced(monkeypatch, fabrics=("oi", "ib"))
+    dev = [e for e in tr.events if e["name"] == "sim.device"]
+    pad = [e for e in tr.events if e["name"] == "sim.pad"]
+    assert len(dev) == len(pad) == len(sent) == 2
+    assert {e["args"]["fabric"] for e in dev} == {"oi", "ib"}
+    for e in dev:
+        assert set(e["args"]) == {"fabric", "rows", "bucket", "retraced"}
+        assert isinstance(e["args"]["retraced"], bool)
+        assert e["args"]["bucket"] >= e["args"]["rows"] > 0
+    # pad and device call are siblings under the scan
+    assert all(p["parent"] == d["parent"] for p, d in zip(pad, dev))
+    c = m.counters
+    assert c["batched_sim.jax_calls"] == 2
+    assert c["batched_sim.jax_rows"] == sum(e["args"]["rows"] for e in dev)
+    assert c["batched_sim.jax_rows"] + c["batched_sim.jax_pad_rows"] == \
+        sum(e["args"]["bucket"] for e in dev)
+    assert c["batched_sim.h2d_bytes"] == sum(
+        a.nbytes for args in sent for a in args)
+    assert all(a.shape[0] == e["args"]["bucket"]
+               for args, e in zip(sent, dev) for a in args)
+    # outputs come back whole (bucket rows, before the slice)
+    assert c["batched_sim.d2h_bytes"] > 0
+
+
+def test_jax_scan_device_span_marks_retrace(monkeypatch):
+    from repro.dse.batched_sim import _jax_terms_fn
+    _jax_terms_fn.cache_clear()
+    tr, _, _ = _jax_study_traced(monkeypatch)
+    tr2, _, _ = _jax_study_traced(monkeypatch)
+    first = [e["args"]["retraced"] for e in tr.events
+             if e["name"] == "sim.device"]
+    again = [e["args"]["retraced"] for e in tr2.events
+             if e["name"] == "sim.device"]
+    assert first == [True] and again == [False]
+
+
+def test_span_mirrored_on_profiler_host_plane(tmp_path):
+    """Under ``tracing()`` with jax imported, every span is also a
+    ``jax.profiler`` annotation: a capture's host plane holds the
+    study's stages and the device call, with their args."""
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+    from repro.api import Study
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing():
+            Study(_tiny_scenario(backend="jax")).run()
+    finally:
+        jax.profiler.stop_trace()
+    xplane, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    host = {}
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host.setdefault(ev.name, dict(ev.stats))
+    assert {"study.run", "study.keep", "sim.device"} <= set(host)
+    assert host["sim.device"]["fabric"] == "oi"
+
+
+def test_obs_never_imports_jax():
+    """The tracer looks jax up and never imports it: numpy-only users
+    of ``repro.obs`` stay free of it, traced or not."""
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "from repro.obs import span, tracing, metrics\n"
+            "with tracing() as tr, metrics.scope():\n"
+            "    with span('a', k=1):\n"
+            "        with span('b'):\n"
+            "            metrics.inc('t.x')\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n"
+            "assert [e['name'] for e in tr.events] == ['b', 'a']\n")
+    subprocess.run([sys.executable, "-c", code], check=True)
 
 
 def test_driver_sweep_populates_cache_counters():
