@@ -57,6 +57,9 @@ KNOWN_COUNTERS = frozenset({
     "outer.event_replayed",
     "outer.variant_cache.hits",
     "outer.variants_evaluated",
+    "pareto.dup_rows",
+    "pareto.rows",
+    "pareto.staircase_rows",
     "profile.kernels",
     "profile.measurements",
 })

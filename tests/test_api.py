@@ -222,6 +222,24 @@ def test_sweep_keep_indices_unique_and_pareto_complete():
     assert np.array_equal(order, np.arange(4))           # top-N first
 
 
+def test_sweep_keep_indices_match_bruteforce_front():
+    import numpy as np
+    from repro.api.study import _sweep_keep_indices
+    from repro.dse.search import sweep_design_space
+    sc = Scenario(**{**TINY, "keep_top": 4})
+    sweep = sweep_design_space(sc.design_space())
+    kept = _sweep_keep_indices(sweep, sc)
+    met = sweep.metrics
+    feas = np.nonzero(met["feasible"])[0]
+    top = feas[np.argsort(-met["throughput"][feas], kind="stable")][:4]
+    M = np.stack([met["throughput"], -met["cost"], -met["power"]], 1)[feas]
+    front = [int(i) for j, i in enumerate(feas)
+             if not ((M >= M[j]).all(1) & (M > M[j]).any(1)).any()]
+    assert len(front) > 4
+    want = list(top) + [i for i in front if i not in set(top.tolist())]
+    assert kept.tolist() == want
+
+
 def test_record_from_search_adapter_matches_cell():
     from repro.api import record_from_search
     from repro.dse.search import BatchedEvaluator, search_exhaustive

@@ -492,29 +492,74 @@ def test_auto_backend_resolution():
 # ---------------------------------------------------------------------------
 # pareto_mask: randomized brute-force cross-check
 # ---------------------------------------------------------------------------
-def test_pareto_mask_matches_bruteforce_randomized():
-    rng = np.random.default_rng(11)
-    for _ in range(15):
-        n = int(rng.integers(1, 250))
-        k = int(rng.integers(1, 4))
+def _pareto_bruteforce(obj, maximize):
+    """The O(N^2) definition: no non-NaN row is >= everywhere and >
+    somewhere."""
+    M = obj * np.where(maximize, 1.0, -1.0)
+    ok = ~np.isnan(M).any(1)
+    want = ok.copy()
+    for j in np.nonzero(ok)[0]:
+        want[j] = not ((M >= M[j]).all(1) & (M > M[j]).any(1) & ok).any()
+    return want
+
+
+def _pareto_case(kind, k, rng):
+    """Objective matrices shaped like what the sweep feeds ``pareto_mask``
+    and like its edge cases."""
+    n = int(rng.integers(2, 2000))
+    if kind == "normal":
         obj = rng.normal(size=(n, k))
-        if n > 20:
-            obj[5:10] = obj[0:5]                 # duplicates
-            obj[10:15, 0] = obj[15:20, 0]        # obj0 ties
-            obj[int(rng.integers(n))] = np.nan
-        maximize = [bool(b) for b in rng.integers(2, size=k)]
-        got = pareto_mask(obj, maximize,
-                          chunk=int(rng.choice([1, 7, 64, 512])))
-        sign = np.where(maximize, 1.0, -1.0)
-        M = obj * sign
-        ok = ~np.isnan(M).any(1)
-        want = ok.copy()
-        for j in range(n):
-            if not want[j]:
-                continue
-            dom = (M >= M[j]).all(1) & (M > M[j]).any(1) & ok
-            want[j] = not dom.any()
-        assert np.array_equal(got, want)
+    elif kind == "few_levels":          # cost-like: ~48 distinct values
+        obj = rng.normal(size=(n, k))
+        obj[:, -1] = rng.integers(48, size=n) * 0.25
+        obj[:, 0] = rng.integers(48, size=n) * 1e3
+    elif kind == "int_grid":            # most rows tie somewhere
+        obj = rng.integers(4, size=(n, k)).astype(float)
+    elif kind == "dup_rows":
+        obj = rng.normal(size=(n, k))
+        obj[rng.integers(n, size=n // 2)] = obj[rng.integers(n, size=n // 2)]
+    elif kind == "obj0_ties":
+        obj = rng.normal(size=(n, k))
+        obj[:, 0] = np.round(obj[:, 0], 1)
+    elif kind == "inf_signed_zero":
+        obj = rng.choice([-np.inf, -1.0, -0.0, 0.0, 1.0, np.inf], size=(n, k))
+        obj[rng.integers(n, size=n // 20)] = np.nan
+    elif kind == "all_nan":
+        obj = np.full((n, k), np.nan)
+    else:                               # single_row
+        obj = rng.normal(size=(1, k))
+    if kind not in ("all_nan", "single_row"):
+        obj[rng.integers(n, size=max(1, n // 100)), rng.integers(k)] = np.nan
+    return obj
+
+
+_PARETO_KINDS = ("normal", "few_levels", "int_grid", "dup_rows",
+                 "obj0_ties", "inf_signed_zero", "all_nan", "single_row")
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 4, 5])
+@pytest.mark.parametrize("kind", _PARETO_KINDS)
+def test_pareto_mask_matches_bruteforce_randomized(kind, k):
+    rng = np.random.default_rng([11, _PARETO_KINDS.index(kind), k])
+    obj = _pareto_case(kind, k, rng)
+    maximize = [bool(b) for b in rng.integers(2, size=k)]
+    want = _pareto_bruteforce(obj, maximize)
+    for chunk in (1, 7, 64, 512):
+        assert np.array_equal(pareto_mask(obj, maximize, chunk=chunk), want)
+
+
+@pytest.mark.parametrize("k", [2, 3, 4])
+def test_pareto_mask_counts_rows(k):
+    from repro.obs import metrics as obs_metrics
+    obj = np.random.default_rng(3).integers(5, size=(300, k)).astype(float)
+    obj[0] = np.nan
+    n_dup = 299 - len(np.unique(obj[1:], axis=0))
+    with obs_metrics.scope() as m:
+        pareto_mask(obj, [True] * k)
+    c = m.counters
+    assert c["pareto.rows"] == 299
+    assert c["pareto.dup_rows"] == n_dup > 0
+    assert c.get("pareto.staircase_rows", 0) == (299 if k <= 3 else 0)
 
 
 def test_inner_search_uses_batched_scan():
