@@ -200,27 +200,42 @@ def adamw(params, grads, m, v, step: int, o: Dict):
 
 
 def train(key_w, key_d, z: Dict, o: Dict, batch: int, seq: int,
-          n_steps: int, operands=None) -> Tuple[list, Dict, Dict]:
-    """``n_steps`` reference steps from the seeded weights and feed.
+          n_steps: int, operands=None, accum: int = 1
+          ) -> Tuple[list, Dict, Dict]:
+    """``n_steps`` reference steps from the seeded weights and feed, each
+    the mean of the gradients (and losses) of ``accum`` micro-batches of
+    ``batch // accum`` consecutive rows, then one AdamW update.
     Returns (losses, per-slice norms of the first clipped gradient,
     per-slice norms of the parameters' change after the last step).
     ``operands`` names a dtype to round matrix-product operands to (the
     control); ``None`` keeps them in float32."""
     r = same if operands is None else rounder(operands)
+    rows = batch // accum
     with jax.default_matmul_precision("highest"):
         init = jax.jit(lambda k: init_params(k, z))
-        grad = jax.jit(jax.value_and_grad(lambda p, b: loss(p, b, z,
-                                                            r=r)))
+        grad = jax.value_and_grad(lambda p, b: loss(p, b, z, r=r))
+
+        def add(p, gsum, b):
+            lval, g = grad(p, b)
+            return lval, jax.tree.map(jnp.add, gsum, g)
+
+        add = jax.jit(add, donate_argnums=1)
         upd = jax.jit(lambda p, g, m, v, s: adamw(p, g, m, v, s, o),
-                      static_argnums=4, donate_argnums=(0, 2, 3))
+                      static_argnums=4, donate_argnums=(0, 1, 2, 3))
         params = init(key_w)
         m = jax.tree.map(jnp.zeros_like, params)
         v = jax.tree.map(jnp.zeros_like, params)
         losses, g1 = [], None
         for step in range(n_steps):
             b = tokens(key_d, step, batch, seq, z["vocab"])
-            lval, g = grad(params, b)
-            losses.append(float(lval))
+            g = jax.tree.map(jnp.zeros_like, params)
+            lsum = 0.0
+            for i in range(accum):
+                lval, g = add(params, g, {k: x[i * rows:(i + 1) * rows]
+                                          for k, x in b.items()})
+                lsum += float(lval)
+            losses.append(lsum / accum)
+            g = jax.tree.map(lambda x: x / accum, g)
             params, m, v, g = upd(params, g, m, v, step + 1)
             if step == 0:
                 g1 = slice_norms(g)

@@ -1,11 +1,12 @@
 """Train cells: ``launch/train.py``'s step, back to back.
 
 Set-up builds the one object the window drives: the sharded train step
-of ``build_sharded_train``, its state (weights made on the device in one
-jitted call from the seed, AdamW moments at zero) and a
-``FaultTolerantLoop`` over the benchmark's own token feed.  It then
-drives that loop through the first three steps, which compile every
-program the window uses and are the steps the reference follows:
+of ``build_sharded_train`` (the gradients of the traffic's
+``micro_batches`` summed before each AdamW update), its state (weights
+made on the device in one jitted call from the seed, AdamW moments at
+zero) and a ``FaultTolerantLoop`` over the benchmark's own token feed.
+It then drives that loop through the first three steps, which compile
+every program the window uses and are the steps the reference follows:
 
 * the loss of each of the three steps;
 * the first gradient as the optimizer got it (clipped), read back from
@@ -74,6 +75,7 @@ class Cell:
         self.opt = config["optimizer"]
         self.itemsize = jnp.dtype(config["compute_dtype"]).itemsize
         self.batch, self.seq = int(traffic["batch"]), int(traffic["seq_len"])
+        self.accum = int(traffic["micro_batches"])
         self.key_w = jax.random.PRNGKey(harness.key31(seed, 10))
         self.key_d = jax.random.PRNGKey(harness.key31(seed, 11))
         self.losses: List[float] = []
@@ -102,6 +104,7 @@ class Cell:
         self.ctx = jax.set_mesh(self.mesh)
         self.ctx.__enter__()
         step_fn, state_sh = build_sharded_train(cfg, ex, self.mesh,
+                                                accum=self.accum,
                                                 base_lr=o["lr"])
         z = self.z
         self.init = jax.jit(lambda k: ref.init_params(k, z),
@@ -146,11 +149,13 @@ class Cell:
     def window(self, seconds: float) -> Dict:
         window_s, done, failed = harness.window_loop(self.unit, seconds)
         n = done + failed
-        tokens = n * self.batch * self.seq
+        tokens = done * self.batch * self.seq      # of completed steps
         return {"window_s": window_s, "units": n, "attempted": n,
                 "failed": failed, "counters": {},
                 "e2e": {"train_tokens_per_s": tokens / window_s},
-                "extra": {"tokens": tokens, "batch": self.batch,
+                # ``batch``: the rows of one micro-batch, one kernel call
+                "extra": {"tokens": tokens,
+                          "batch": self.batch // self.accum,
                           "seq": self.seq, "sizes": dict(self.z),
                           "itemsize": self.itemsize}}
 
@@ -166,7 +171,7 @@ class Cell:
     def check(self) -> List[Tuple[str, float, float]]:
         losses, g1, delta = ref.train(self.key_w, self.key_d, self.z,
                                       self.opt, self.batch, self.seq,
-                                      N_CHECKED)
+                                      N_CHECKED, accum=self.accum)
         got = compare(self.checked_losses, self.g1, self.delta,
                       losses, g1, delta)
         harness.say("check losses program=" + ",".join(

@@ -97,7 +97,7 @@ def fp8_in_place(cell) -> None:
     from chipbench.runners.train import N_CHECKED
     cell.checked_losses, cell.g1, cell.delta = ref.train(
         cell.key_w, cell.key_d, cell.z, cell.opt, cell.batch, cell.seq,
-        N_CHECKED, operands="float8_e4m3fn")
+        N_CHECKED, operands="float8_e4m3fn", accum=cell.accum)
 
 
 def shrink(spec) -> None:
@@ -106,11 +106,11 @@ def shrink(spec) -> None:
         spec.config.update(hidden_size=64, intermediate_size=128,
                            num_attention_heads=4, num_key_value_heads=2,
                            num_hidden_layers=2, vocab_size=512)
-        spec.traffic.update(seq_len=256)
-        # 512 tokens average bfloat16's rounding less than the cell's
-        # 8192: sound runs read up to loss_rel 1.5e-4, grad_gap 3.5e-3,
-        # update_gap 2.0e-3 on the CPU at this size, the control at
-        # least 1.2e-3, 1.2e-2, 6.8e-3 (six and three seeds)
+        spec.traffic.update(seq_len=256, batch=4, micro_batches=2)
+        # 512 tokens a micro-batch average bfloat16's rounding less than
+        # the cell's 8192: sound runs read up to loss_rel 1.3e-4,
+        # grad_gap 2.9e-3, update_gap 1.9e-3 on the CPU at this size, the
+        # control at least 4.3e-4, 1.9e-2, 7.0e-3 (six and three seeds)
         spec.traffic["limits"] = {"loss_rel": 5e-4, "grad_gap": 7e-3,
                                   "update_gap": 4e-3}
     else:
@@ -118,26 +118,12 @@ def shrink(spec) -> None:
         spec.traffic["C"] = {"low": 4e6, "high": 8e6}
 
 
-def find(workload: str):
-    """The cell by name: from BENCHMARK.json, or, for a cell written but
-    not yet measured on the chip, from ``data/<workload>.json``, the
-    entries it would add there."""
-    from chipbench import harness
-    pending = Path(__file__).resolve().parent / "data" / f"{workload}.json"
-    if not pending.is_file():
-        return harness.find_cell(workload)
-    bench = harness.load_benchmark()
-    for key, entries in json.loads(pending.read_text()).items():
-        bench[key] = bench[key] + entries
-    return harness.find_cell(workload, bench)
-
-
 def run(workload: str, variant: str, seeds: List[int], seconds: float,
         tiny: bool = False, devices=None) -> List[Dict]:
     from chipbench import harness
     out = []
     for seed in seeds:
-        spec = find(workload)
+        spec = harness.find_cell(workload)
         if tiny:
             shrink(spec)
         runner = harness.load_runner(spec.config)
@@ -177,7 +163,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     from chipbench import harness
     harness.use_cache_dir()
-    spec = find(args.workload)
+    spec = harness.find_cell(args.workload)
     devices = harness.chips(int(spec.workload["chips"]))
     for variant in args.variant.split(","):
         for row in run(args.workload, variant,

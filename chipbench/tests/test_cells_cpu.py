@@ -1,9 +1,8 @@
 """Each runner end to end on the CPU at a tiny size, through the
 harness's own run (set-up, window, free, reference, comparison), and the
 same run with the timed path broken underneath: every fault a cell can
-have, and the control, must come out not correct.  ``internlm2_train``
-is not in BENCHMARK.json yet (see PERF.md); its entries wait in
-``data/internlm2_train.json``."""
+have, and the control, must come out not correct.  The cells are read
+from BENCHMARK.json as it stands."""
 import json
 
 import jax
@@ -18,7 +17,7 @@ TRAIN = "internlm2_train"
 
 @pytest.mark.parametrize("cell", [STUDY, TRAIN])
 def test_run_cell_prints_a_correct_result(cell, capsys):
-    spec = control.find(cell)
+    spec = harness.find_cell(cell)
     control.shrink(spec)
     rc = harness.run_cell(spec, 2 ** 31 + 3, 1.0, False, jax.devices()[:1],
                           t0=0.0)
