@@ -207,8 +207,9 @@ def build_scenarios(args) -> List[Scenario]:
         base.setdefault("total_tflops", 4e6)
         models = [base.pop("model", "qwen3_moe_235b_a22b")]
         if models == ["all"]:
-            from repro.configs import ARCH_IDS
-            models = list(ARCH_IDS)
+            from repro.configs import ARCH_IDS, get_config
+            from repro.core.workload import uncosted
+            models = [a for a in ARCH_IDS if not uncosted(get_config(a))]
         out = [Scenario(model=m, **base) for m in models]
     if args.quick:
         out = [_quick(sc) for sc in out]

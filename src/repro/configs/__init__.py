@@ -17,6 +17,7 @@ ARCH_IDS = [
     "gemma3_27b",
     "tinyllama_1_1b",
     "internlm2_20b",
+    "deepseek_v2_lite",
 ]
 
 _ALIASES = {i.replace("_", "-"): i for i in ARCH_IDS}

@@ -26,6 +26,23 @@ class AttnConfig:
     local_global_period: int = 0
     attn_softcap: float = 0.0
     qk_norm: bool = False
+    # Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
+    # keys and values come from a shared latent of ``kv_lora_rank`` plus
+    # one RoPE key of ``qk_rope_head_dim`` broadcast over the heads.
+    # ``head_dim`` is then the q/k head size (nope + rope).  None = MHA/GQA.
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
+    def v_dim(self) -> int:
+        """Per-head size of the values (and of the attention output)."""
+        return self.v_head_dim if self.mla else self.head_dim
 
 
 @dataclass(frozen=True)
@@ -34,8 +51,30 @@ class MoEConfig:
     top_k: int
     d_ff_expert: int
     router_jitter: float = 0.0
-    # capacity factor for padded (sort-based) dispatch
-    capacity_factor: float = 1.25
+    # capacity factor for padded (sort-based) dispatch; None = dropless:
+    # every routed token is computed, through the grouped-matmul kernel
+    capacity_factor: Optional[float] = 1.25
+    # shared experts, applied to every token (DeepSeekMoE); merged into
+    # one SwiGLU MLP of n_shared * d_ff_expert
+    n_shared: int = 0
+    # renormalise the top-k router weights to sum to one
+    norm_topk: bool = True
+    # load-balance loss: sequence-wise (arXiv:2405.04434 §2.2) or the
+    # Switch-style loss over the whole batch; weight in the loss
+    seq_aux: bool = False
+    aux_alpha: float = 0.01
+    # routed experts this chip holds (its expert-parallel share); the
+    # router still scores all ``n_experts``.  None = all of them.
+    experts_held: Optional[int] = None
+
+    @property
+    def d_ff_shared(self) -> int:
+        return self.n_shared * self.d_ff_expert
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
 
 
 @dataclass(frozen=True)
@@ -82,6 +121,8 @@ class ModelConfig:
     gated_mlp: bool = True
     # long_500k eligibility (sub-quadratic decode path); see DESIGN.md.
     supports_long_context: bool = False
+    # leading layers with a dense MLP of ``d_ff`` before the MoE layers
+    first_k_dense: int = 0
     source: str = ""
 
     # ------------------------------------------------------------------
@@ -96,11 +137,15 @@ class ModelConfig:
             attn = dataclasses.replace(
                 self.attn, n_heads=4, n_kv_heads=2, head_dim=16,
                 window=(16 if self.attn.window else None))
+            if self.attn.mla:
+                attn = dataclasses.replace(
+                    attn, n_kv_heads=4, head_dim=24, kv_lora_rank=32,
+                    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
         moe = None
         if self.moe is not None:
             moe = dataclasses.replace(
                 self.moe, n_experts=4, top_k=min(2, self.moe.top_k),
-                d_ff_expert=32)
+                d_ff_expert=32, experts_held=None)
         ssm = None
         if self.ssm is not None:
             ssm = dataclasses.replace(self.ssm, d_state=16, head_dim=8,
@@ -122,6 +167,12 @@ class ModelConfig:
         if a is None:
             return 0
         d = self.d_model
+        if a.mla:
+            r = a.kv_lora_rank
+            return (d * a.n_heads * a.head_dim                  # q
+                    + d * (r + a.qk_rope_head_dim) + r          # kv latent
+                    + r * a.n_heads * (a.qk_nope_head_dim + a.v_head_dim)
+                    + a.n_heads * a.v_head_dim * d)             # o
         return (d * a.n_heads * a.head_dim            # q
                 + 2 * d * a.n_kv_heads * a.head_dim   # k, v
                 + a.n_heads * a.head_dim * d)         # o
@@ -155,14 +206,20 @@ class ModelConfig:
         p = self._attn_params() + 2 * d
         if self.moe is not None:
             router = d * self.moe.n_experts
-            p += router + self.moe.n_experts * self._mlp_params(
+            p += router + self.moe.held * self._mlp_params(
                 self.moe.d_ff_expert)
+            p += self._mlp_params(self.moe.d_ff_shared) \
+                if self.moe.n_shared else 0
         else:
             p += self._mlp_params(self.d_ff)
         return p
 
     def param_count(self) -> int:
         p = self.n_layers * self.layer_params()
+        if self.first_k_dense:
+            dense = self._attn_params() + 2 * self.d_model \
+                + self._mlp_params(self.d_ff)
+            p += self.first_k_dense * (dense - self.layer_params())
         p += self.vocab * self.d_model  # embedding
         if not self.tie_embeddings:
             p += self.vocab * self.d_model
@@ -183,7 +240,12 @@ class ModelConfig:
         d = self.d_model
         dense_layer = self._attn_params() + 2 * d + d * self.moe.n_experts
         active_ffn = self.moe.top_k * self._mlp_params(self.moe.d_ff_expert)
+        if self.moe.n_shared:
+            active_ffn += self._mlp_params(self.moe.d_ff_shared)
         p = self.n_layers * (dense_layer + active_ffn)
+        if self.first_k_dense:
+            p += self.first_k_dense * (self._mlp_params(self.d_ff)
+                                       - d * self.moe.n_experts - active_ffn)
         p += self.vocab * d + d
         return p
 
@@ -204,7 +266,9 @@ class ModelConfig:
                 if a.local_global_period:
                     frac_local = (a.local_global_period - 1) / a.local_global_period
                 eff = frac_local * min(a.window, seq_len) + (1 - frac_local) * seq_len
-            base += n_attn_layers * 4.0 * a.n_heads * a.head_dim * (eff / 2.0)
+            # scores over q/k head dims, values over v head dims
+            base += (n_attn_layers * 2.0 * a.n_heads * (a.head_dim + a.v_dim)
+                     * (eff / 2.0))
         return base
 
 

@@ -7,8 +7,17 @@ analytic traffic model and the compiled dry-run HLO therefore describe the
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import List
 
 from repro.configs.base import ModelConfig
+
+
+def uncosted(m: ModelConfig) -> List[str]:
+    """The mechanisms of ``m`` the cluster model cannot cost yet."""
+    return [what for what, has in (
+        ("latent attention", m.attn is not None and m.attn.mla),
+        ("shared experts", m.moe is not None and m.moe.n_shared),
+        ("leading dense layers", m.first_k_dense)) if has]
 
 
 @dataclass(frozen=True)
@@ -19,6 +28,15 @@ class Workload:
     bytes_act: int = 2         # bf16 activations
     bytes_grad: int = 4        # fp32 gradient all-reduce (Megatron default)
     bytes_param: int = 2
+
+    def __post_init__(self):
+        m = self.model
+        missing = uncosted(m)
+        if missing:
+            raise ValueError(
+                f"{m.name}: {', '.join(missing)} not costed by the cluster "
+                f"model yet (ROADMAP B2, B3); it would be costed as plain "
+                f"GQA / MoE layers")
 
     @property
     def tokens_per_step(self) -> int:

@@ -84,13 +84,14 @@ def _fa_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 def flash_attention_fwd(q, k, v, window=None, *, causal=True, softcap=0.0,
                         scale=None, block_q=128, block_k=128,
                         interpret=False):
-    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D); returns (B, Hq, Sq, D).
+    """q, k: (B, Hq|Hkv, S, D); v: (B, Hkv, S, Dv); returns (B, Hq, Sq, Dv)
+    and the log-sum-exp rows.  Dv may differ from D (latent attention).
 
     ``window``: None (full), python int, or int32 scalar array (dynamic).
     Assumes Sq == Sk (training / prefill self-attention).
     """
     b, hq, sq, d = q.shape
-    hkv, sk = k.shape[1], k.shape[2]
+    hkv, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
     assert sq == sk, "fwd kernel is for self-attention (train/prefill)"
     if scale is None:
         scale = d ** -0.5
@@ -116,10 +117,10 @@ def flash_attention_fwd(q, k, v, window=None, *, causal=True, softcap=0.0,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d), kv_map),
-            pl.BlockSpec((1, 1, block_k, d), kv_map),
+            pl.BlockSpec((1, 1, block_k, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv),
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
             # lse as a (block_q, 1) column: a block's last two dims must
             # tile (8, 128) or span the array, and a trailing 1 spans it
@@ -127,11 +128,11 @@ def flash_attention_fwd(q, k, v, window=None, *, causal=True, softcap=0.0,
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],

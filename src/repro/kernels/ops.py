@@ -104,7 +104,7 @@ def _fa_fwd_xla_blocked(q, k, v, window, causal, softcap, scale, block):
     for qi in range(nq):
         qb = qf[:, :, qi * blkq:(qi + 1) * blkq]
         rows = qi * blkq + jnp.arange(blkq)[:, None] + (sk - sq)
-        acc = jnp.zeros((b, hq, blkq, d), f32)
+        acc = jnp.zeros((b, hq, blkq, v.shape[-1]), f32)
         m = jnp.full((b, hq, blkq), NEG_INF, f32)
         l = jnp.zeros((b, hq, blkq), f32)
         for ki in range(nk):
@@ -193,13 +193,15 @@ def _fa_bwd_xla_blocked(q, k, v, o, lse, do, window, causal, softcap,
             dq_b += jnp.einsum("bhqk,bhkd->bhqd", ds, kb)
             dk_q = jnp.einsum("bhqk,bhqd->bhkd", ds, qb)
             dk_q = dk_q.reshape(b, hkv, group, blkk, d).sum(2)
-            dv_q = dv_q.reshape(b, hkv, group, blkk, d).sum(2)
+            dv_q = dv_q.reshape(b, hkv, group, blkk, -1).sum(2)
             dk_acc[ki] = dk_q if dk_acc[ki] is None else dk_acc[ki] + dk_q
             dv_acc[ki] = dv_q if dv_acc[ki] is None else dv_acc[ki] + dv_q
         dq_blocks.append(dq_b)
     zero = jnp.zeros((b, hkv, blkk, d), f32)
+    zero_v = jnp.zeros((b, hkv, blkk, v.shape[-1]), f32)
     dk = jnp.concatenate([x if x is not None else zero for x in dk_acc], 2)
-    dv = jnp.concatenate([x if x is not None else zero for x in dv_acc], 2)
+    dv = jnp.concatenate([x if x is not None else zero_v for x in dv_acc],
+                         2)
     dq = jnp.concatenate(dq_blocks, 2)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
@@ -216,7 +218,7 @@ def _fa_fwd_xla(q, k, v, window, causal, softcap, scale, block_k):
     rows = jnp.arange(sq)[:, None] + (sk - sq)
 
     kb = jnp.moveaxis(k.reshape(b, hkv, nk, bk, d), 2, 0)
-    vb = jnp.moveaxis(v.reshape(b, hkv, nk, bk, d), 2, 0)
+    vb = jnp.moveaxis(v.reshape(b, hkv, nk, bk, v.shape[-1]), 2, 0)
 
     def step(carry, inp):
         acc, m, l = carry
@@ -238,7 +240,7 @@ def _fa_fwd_xla(q, k, v, window, causal, softcap, scale, block_k):
         acc = acc * alpha[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, vblk)
         return (acc, m_new, l), None
 
-    acc0 = jnp.zeros((b, hq, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, hq, sq, v.shape[-1]), jnp.float32)
     m0 = jnp.full((b, hq, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hq, sq), jnp.float32)
     (acc, m, l), _ = jax.lax.scan(step, (acc0, m0, l0),
@@ -264,7 +266,7 @@ def _fa_bwd_xla(q, k, v, o, lse, do, window, causal, softcap, scale,
     cols = jnp.arange(sk)[None, :]
 
     qb = jnp.moveaxis(q.reshape(b, hq, nq, bq, d), 2, 0).astype(f32)
-    dob = jnp.moveaxis(do.reshape(b, hq, nq, bq, d), 2, 0).astype(f32)
+    dob = jnp.moveaxis(do.reshape(b, hq, nq, bq, -1), 2, 0).astype(f32)
     lseb = jnp.moveaxis(lse.reshape(b, hq, nq, bq), 2, 0)
     # delta_i = rowsum(dO * O)
     delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1)
@@ -297,11 +299,11 @@ def _fa_bwd_xla(q, k, v, o, lse, do, window, causal, softcap, scale,
         dk_q = jnp.einsum("bhqk,bhqd->bhkd", ds, qblk)
         # GQA: sum gradients over the head group
         dk_q = dk_q.reshape(b, hkv, group, sk, d).sum(2)
-        dv_q = dv_q.reshape(b, hkv, group, sk, d).sum(2)
+        dv_q = dv_q.reshape(b, hkv, group, sk, -1).sum(2)
         return (dk + dk_q, dv + dv_q), dq_b
 
     dk0 = jnp.zeros((b, hkv, sk, d), f32)
-    dv0 = jnp.zeros((b, hkv, sk, d), f32)
+    dv0 = jnp.zeros(v.shape, f32)
     (dk, dv), dqb = jax.lax.scan(step, (dk0, dv0),
                                  (jnp.arange(nq), qb, dob, lseb, deltab))
     dq = jnp.moveaxis(dqb, 0, 2).reshape(b, hq, sq, d)
@@ -376,7 +378,8 @@ _flash_attention.defvjp(_fa_vjp_fwd, _flash_attention_bwd_rule)
 
 def flash_attention(q, k, v, *, window=None, causal=True, softcap=0.0,
                     scale=None, block=128, backend=None):
-    """Memory-efficient attention.  q: (B,Hq,S,D); k/v: (B,Hkv,S,D).
+    """Memory-efficient attention.  q: (B,Hq,S,D); k: (B,Hkv,S,D);
+    v: (B,Hkv,S,Dv), Dv free (latent attention); returns (B,Hq,S,Dv).
 
     ``window`` may be None, an int, or a traced int32 scalar (dynamic
     local/global switching inside a scanned layer stack).
@@ -414,24 +417,75 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=None, softcap=0.0,
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgk,bhkd->bhgd", p, v_cache.astype(jnp.float32))
-    return o.reshape(b, hq, 1, d).astype(q.dtype)
+    return o.reshape(b, hq, 1, -1).astype(q.dtype)
 
 
 # ===========================================================================
-# MoE dispatch / grouped matmul
+# MoE grouped matmul
 # ===========================================================================
-def moe_gmm(x, w, group_sizes_or_blockids, *, backend=None, block_t=128):
-    """Grouped matmul over expert-sorted tokens.
+# An expert's whole (K, N) weight is one tile where it fits in this many
+# bytes, so the consecutive row blocks of an expert fetch it once.
+_GMM_WEIGHT_TILE = 8 * 1024 * 1024
 
-    pallas: expects block ids per token-block.  xla: expects a dense batched
-    form — used by the model layer (see models/moe.py which builds padded
-    (E, cap, d) buckets and einsums); this wrapper handles the sorted-rows
-    layout used by the kernel tests.
+
+def _gmm_pallas_rows(x, w, group_sizes, block_t):
+    t, kdim = x.shape
+    e, _, n = w.shape
+    if kdim * n * w.dtype.itemsize <= _GMM_WEIGHT_TILE:
+        bk, bn = kdim, n
+    else:
+        bk, bn = _pick_block(kdim, 512), _pick_block(n, 512)
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    starts = jnp.arange(t // block_t, dtype=jnp.int32) * block_t
+    gid = jnp.minimum(jnp.searchsorted(ends, starts, side="right"), e - 1)
+    call = [functools.partial(_gmm_pallas, block_t=block_t, block_n=bn,
+                              block_k=bk, interpret=i) for i in (False, True)]
+    # the Mosaic kernel on the TPU, the same kernel interpreted elsewhere
+    out = jax.lax.platform_dependent(x, w, gid, ends[-1] // block_t,
+                                     tpu=call[0], default=call[1])
+    # the kernel leaves the rows past the live blocks unwritten
+    return jnp.where((jnp.arange(t) < ends[-1])[:, None], out, 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _moe_gmm(x, w, group_sizes, block_t, backend):
+    if backend == "pallas":
+        return _gmm_pallas_rows(x, w, group_sizes, block_t)
+    return jax.lax.ragged_dot(x, w, group_sizes)
+
+
+def _moe_gmm_fwd(x, w, group_sizes, block_t, backend):
+    return _moe_gmm(x, w, group_sizes, block_t, backend), (x, w, group_sizes)
+
+
+def _moe_gmm_bwd(block_t, backend, res, dy):
+    import numpy as np
+    x, w, group_sizes = res
+    # XLA's grouped matmuls (dx against the transposed weights, dw over
+    # each group's rows)
+    _, vjp = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, group_sizes),
+                     x, w)
+    dx, dw = vjp(dy)
+    return dx, dw, np.zeros(group_sizes.shape, jax.dtypes.float0)
+
+
+_moe_gmm.defvjp(_moe_gmm_fwd, _moe_gmm_bwd)
+
+
+def moe_gmm(x, w, group_sizes, *, backend=None, block_t=128):
+    """Grouped matmul over expert-sorted rows: rows of group ``g`` (the
+    ``group_sizes[g]`` rows after the groups before it) times ``w[g]``;
+    rows past ``sum(group_sizes)`` give zeros.  x: (T, K); w: (E, K, N);
+    group_sizes: (E,) int32, may be traced.
+
+    pallas: the ``moe_gmm`` kernel, every group a whole number of
+    ``block_t`` rows, and only the blocks up to the last group computed
+    (T may be sized for the worst case).  xla: ``lax.ragged_dot``.  The
+    backward is XLA's ragged matmuls on either backend.
     """
     backend = backend or default_backend()
-    if backend == "pallas":
-        return _gmm_pallas(x, w, group_sizes_or_blockids, block_t=block_t)
-    return _ref.moe_gmm_ref(x, w, group_sizes_or_blockids)
+    return _moe_gmm(x, w, jnp.asarray(group_sizes, jnp.int32), int(block_t),
+                    backend)
 
 
 # ===========================================================================

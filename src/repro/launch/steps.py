@@ -10,6 +10,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import build_model
 from repro.models.common import ExecConfig
+from repro.obs.metrics import reduce_stacked
 from repro.optim import AdamWState, adamw_init, adamw_update, \
     cosine_schedule
 
@@ -51,19 +52,21 @@ def make_train_step(cfg: ModelConfig, ex: ExecConfig, *, base_lr=3e-4,
         else:
             def micro(carry, mb):
                 gsum, lsum = carry
-                (l, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                (l, m), g = jax.value_and_grad(loss_fn, has_aux=True)(
                     state.params, mb)
-                return (jax.tree.map(jnp.add, gsum, g), lsum + l), None
+                # the loss's own stats (ce, aux aside), per micro-batch
+                stats = {k: v for k, v in m.items() if k not in ("ce", "aux")}
+                return (jax.tree.map(jnp.add, gsum, g), lsum + l), stats
 
             mbs = jax.tree.map(
                 lambda x: x.reshape(accum, x.shape[0] // accum,
                                     *x.shape[1:]), batch)
             zero = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-            (gsum, lsum), _ = jax.lax.scan(micro, (zero, 0.0), mbs)
+            (gsum, lsum), stats = jax.lax.scan(micro, (zero, 0.0), mbs)
             grads = jax.tree.map(lambda g: g / accum, gsum)
             loss = lsum / accum
-            metrics = {"ce": loss, "aux": 0.0}
+            metrics = {"ce": loss, "aux": 0.0, **reduce_stacked(stats)}
         new_params, new_opt, om = adamw_update(state.params, grads,
                                                state.opt, lr_fn)
         metrics = dict(metrics, loss=loss, **om)

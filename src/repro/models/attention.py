@@ -5,6 +5,8 @@ window scalar so a single scanned layer stack serves both layer kinds.
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -14,6 +16,8 @@ from repro.models import common
 
 
 def attn_init(key, d_model, a: AttnConfig, dtype):
+    if a.mla:
+        return _mla_init(key, d_model, a, dtype)
     ks = jax.random.split(key, 6)
     p = {
         "wq": common.dense_init(ks[0], d_model, a.n_heads * a.head_dim,
@@ -31,8 +35,47 @@ def attn_init(key, d_model, a: AttnConfig, dtype):
     return p
 
 
+def _mla_init(key, d_model, a: AttnConfig, dtype):
+    ks = jax.random.split(key, 4)
+    h, r = a.n_heads, a.kv_lora_rank
+    return {
+        "wq": common.dense_init(ks[0], d_model, h * a.head_dim, dtype),
+        "wkv_a": common.dense_init(ks[1], d_model, r + a.qk_rope_head_dim,
+                                   dtype),
+        "kv_norm": jnp.ones((r,), dtype),
+        "wkv_b": common.dense_init(
+            ks[2], r, h * (a.qk_nope_head_dim + a.v_head_dim), dtype),
+        "wo": common.dense_init(ks[3], h * a.v_head_dim, d_model, dtype),
+    }
+
+
+def _project_mla(params, x, a: AttnConfig, positions, norm_eps, backend):
+    """Latent attention's q, k, v (arXiv:2405.04434 eqs. 9-19, no q LoRA):
+    q = W_q h split into nope and rope parts per head; [c_kv, k_rope] =
+    W_kva h with RMSNorm on c_kv; [k_nope, v] = W_kvb c_kv per head;
+    RoPE on q_rope and on the one k_rope, which every head shares.
+    Returns (B,H,S,nope+rope) q and k, and (B,H,S,v_head_dim) v."""
+    b, s, _ = x.shape
+    h, r = a.n_heads, a.kv_lora_rank
+    nope, rd = a.qk_nope_head_dim, a.qk_rope_head_dim
+    q = jnp.moveaxis((x @ params["wq"]).reshape(b, s, h, nope + rd), 1, 2)
+    kv = x @ params["wkv_a"]
+    c_kv = common.norm(kv[..., :r], params["kv_norm"], norm_eps, backend)
+    k_rope = common.apply_rope(kv[:, None, :, r:], positions, a.rope_theta)
+    kvb = jnp.moveaxis((c_kv @ params["wkv_b"]).reshape(
+        b, s, h, nope + a.v_head_dim), 1, 2)
+    q = jnp.concatenate(
+        [q[..., :nope], common.apply_rope(q[..., nope:], positions,
+                                          a.rope_theta)], -1)
+    k = jnp.concatenate(
+        [kvb[..., :nope], jnp.broadcast_to(k_rope, (b, h, s, rd))], -1)
+    return q, k, kvb[..., nope:]
+
+
 def _project_qkv(params, x, a: AttnConfig, positions, norm_eps, backend,
                  rope=True):
+    if a.mla:
+        return _project_mla(params, x, a, positions, norm_eps, backend)
     b, s, _ = x.shape
     q = (x @ params["wq"]).reshape(b, s, a.n_heads, a.head_dim)
     k = (x @ params["wk"]).reshape(b, s, a.n_kv_heads, a.head_dim)
@@ -76,6 +119,12 @@ def attn_train(params, x, a: AttnConfig, *, window=None, norm_eps, ex,
     caller's concern).  Returns (out, (k, v)) with k/v pre-rope-cache
     layout (B,Hkv,S,D) for prefill cache building.
     """
+    with (jax.named_scope("mla") if a.mla else contextlib.nullcontext()):
+        return _attn_train(params, x, a, window, norm_eps, ex, causal,
+                           kv_source)
+
+
+def _attn_train(params, x, a, window, norm_eps, ex, causal, kv_source):
     b, s, _ = x.shape
     positions = jnp.arange(s)
     if kv_source is None:
@@ -98,7 +147,7 @@ def attn_train(params, x, a: AttnConfig, *, window=None, norm_eps, ex,
         o = ops.flash_attention(q, k, v, window=None, causal=False,
                                 softcap=a.attn_softcap, block=ex.attn_block,
                                 backend=ex.backend)
-    out = jnp.moveaxis(o, 1, 2).reshape(b, s, a.n_heads * a.head_dim)
+    out = jnp.moveaxis(o, 1, 2).reshape(b, s, a.n_heads * a.v_dim)
     return out @ params["wo"], (k, v)
 
 
@@ -130,7 +179,7 @@ def attn_decode(params, x, cache_k, cache_v, pos, a: AttnConfig, *,
         window = layer_window(a, is_global, smax)
         o = ops.decode_attention(q, cache_k, cache_v, pos, window=window,
                                  softcap=a.attn_softcap)
-    out = jnp.moveaxis(o, 1, 2).reshape(b, 1, a.n_heads * a.head_dim)
+    out = jnp.moveaxis(o, 1, 2).reshape(b, 1, a.n_heads * a.v_dim)
     return out @ params["wo"], cache_k, cache_v
 
 

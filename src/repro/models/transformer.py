@@ -3,6 +3,8 @@
 One scanned, homogeneous layer stack serves every layer: local/global
 attention alternation is a per-layer dynamic window scalar (gemma), MoE vs
 dense is static per-arch.  Compile time and HLO size are O(1) in depth.
+The ``first_k_dense`` leading dense layers of a MoE model (DeepSeek) run
+unscanned before it, from their own stacked params (``dense_layers``).
 """
 from __future__ import annotations
 
@@ -11,19 +13,20 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention, common, moe
+from repro.obs import metrics as obs_metrics
 
 
 # ---------------------------------------------------------------------------
 # Params
 # ---------------------------------------------------------------------------
-def layer_init(key, cfg: ModelConfig, dtype):
+def layer_init(key, cfg: ModelConfig, dtype, dense: bool = False):
     ks = jax.random.split(key, 4)
     p = {
         "ln1": jnp.ones((cfg.d_model,), dtype),
         "ln2": jnp.ones((cfg.d_model,), dtype),
         "attn": attention.attn_init(ks[0], cfg.d_model, cfg.attn, dtype),
     }
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         p["moe"] = moe.moe_init(ks[1], cfg.d_model, cfg.moe, dtype)
     else:
         p["mlp"] = common.mlp_init(ks[1], cfg.d_model, cfg.d_ff,
@@ -35,13 +38,19 @@ def lm_init(key, cfg: ModelConfig, ex: common.ExecConfig):
     dtype = ex.param_dtype
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    layers = jax.vmap(lambda k: layer_init(k, cfg, dtype))(layer_keys)
+    n_dense = cfg.first_k_dense
+    layers = jax.vmap(lambda k: layer_init(k, cfg, dtype))(
+        layer_keys[n_dense:])
     params = {
         "embed": common.initializer(k_embed, (cfg.vocab, cfg.d_model),
                                     0.02, dtype),
         "layers": layers,
         "final_norm": jnp.ones((cfg.d_model,), dtype),
     }
+    if n_dense:
+        params["dense_layers"] = jax.vmap(
+            lambda k: layer_init(k, cfg, dtype, dense=True))(
+                layer_keys[:n_dense])
     if not cfg.tie_embeddings:
         params["lm_head"] = common.dense_init(k_head, cfg.d_model,
                                               cfg.vocab, dtype)
@@ -49,8 +58,9 @@ def lm_init(key, cfg: ModelConfig, ex: common.ExecConfig):
 
 
 def layer_flags(cfg: ModelConfig):
-    """(L,) int32 — 1 where the layer uses GLOBAL (full) attention."""
-    l = cfg.n_layers
+    """(L,) int32 — 1 where the scanned layer uses GLOBAL (full)
+    attention."""
+    l = cfg.n_layers - cfg.first_k_dense
     if cfg.attn is None or cfg.attn.local_global_period == 0:
         return jnp.ones((l,), jnp.int32)   # uniform (window handled statically)
     p = cfg.attn.local_global_period
@@ -85,22 +95,36 @@ def _cache_len(cfg: ModelConfig, seq_len: int):
 # ---------------------------------------------------------------------------
 # Forward passes
 # ---------------------------------------------------------------------------
+def _ffn(lp, h, cfg, ex):
+    """The layer's MLP or MoE -> (out, aux_loss, stats); which one is
+    decided by the layer's params (``mlp`` or ``moe``)."""
+    if "moe" not in lp:
+        return common.mlp_apply(lp["mlp"], h, cfg.gated_mlp), 0.0, {}
+    if ex.moe_impl == "a2a" and ex.mesh is not None:
+        if cfg.moe.capacity_factor is None:
+            raise ValueError("the a2a dispatch is capacity-padded; "
+                             f"{cfg.name} routes dropless")
+        from repro.parallel.moe_a2a import moe_apply_a2a
+        m, aux = moe_apply_a2a(lp["moe"], h, cfg.moe, ex, ex.mesh)
+        return m, aux, {}
+    return moe.moe_apply(lp["moe"], h, cfg.moe, ex)
+
+
 def _layer_train(x, lp, window, cfg, ex, collect_kv=False):
     h = common.norm(x, lp["ln1"], cfg.norm_eps, ex.backend)
     a, kv = attention.attn_train(lp["attn"], h, cfg.attn, window=window,
                                  norm_eps=cfg.norm_eps, ex=ex)
     x = x + a
     h = common.norm(x, lp["ln2"], cfg.norm_eps, ex.backend)
-    if cfg.moe is not None:
-        if ex.moe_impl == "a2a" and ex.mesh is not None:
-            from repro.parallel.moe_a2a import moe_apply_a2a
-            m, aux = moe_apply_a2a(lp["moe"], h, cfg.moe, ex, ex.mesh)
-        else:
-            m, aux = moe.moe_apply(lp["moe"], h, cfg.moe, ex)
-    else:
-        m, aux = common.mlp_apply(lp["mlp"], h, cfg.gated_mlp), 0.0
+    m, aux, stats = _ffn(lp, h, cfg, ex)
     x = common.shard_acts(x + m, ex)
-    return x, aux, (kv if collect_kv else None)
+    return x, aux, (kv if collect_kv else None), stats
+
+
+def _dense_layers(params, cfg: ModelConfig):
+    """The leading dense layers' params, one dict per layer."""
+    return [jax.tree.map(lambda t: t[i], params["dense_layers"])
+            for i in range(cfg.first_k_dense)]
 
 
 def _use_period_path(cfg: ModelConfig, ex) -> bool:
@@ -125,9 +149,17 @@ def _period_window(cfg: ModelConfig, j: int):
 
 
 def lm_hidden(params, tokens, cfg: ModelConfig, ex, prefix_embeds=None):
-    """Full-sequence forward -> (hidden (B,S,D), aux_loss)."""
+    """Full-sequence forward -> (hidden (B,S,D), aux_loss, stats): the MoE
+    layers' dispatch counts summed over the layers."""
     x = _embed(params, tokens, cfg, ex, prefix_embeds)
     s = x.shape[1]
+    aux = 0.0
+    if cfg.first_k_dense:
+        window = attention.layer_window(cfg.attn, True, s)
+        dense = ex.wrap_remat(
+            lambda x_, lp_: _layer_train(x_, lp_, window, cfg, ex)[0])
+        for lp in _dense_layers(params, cfg):
+            x = dense(x, lp)
 
     if _use_period_path(cfg, ex):
         p = cfg.attn.local_global_period
@@ -138,17 +170,19 @@ def lm_hidden(params, tokens, cfg: ModelConfig, ex, prefix_embeds=None):
             x, aux = carry
             for j in range(p):
                 lp = jax.tree.map(lambda t: t[j], lp_grp)
-                x, a_, _ = _layer_train(x, lp, _period_window(cfg, j),
-                                        cfg, ex)
+                x, a_, _, _ = _layer_train(x, lp, _period_window(cfg, j),
+                                           cfg, ex)
                 aux = aux + a_
             return (x, aux), None
 
         pbody = ex.wrap_remat(pbody)
-        (x, aux), _ = common.layer_scan(ex, pbody, (x, 0.0), main)
+        (x, aux), _ = common.layer_scan(ex, pbody, (x, aux), main)
         for j in range(n_rest):
             lp = jax.tree.map(lambda t: t[j], rest)
-            x, a_, _ = _layer_train(x, lp, _period_window(cfg, j), cfg, ex)
+            x, a_, _, _ = _layer_train(x, lp, _period_window(cfg, j), cfg,
+                                       ex)
             aux = aux + a_
+        stats = {}
     else:
         flags = layer_flags(cfg)
 
@@ -157,25 +191,26 @@ def lm_hidden(params, tokens, cfg: ModelConfig, ex, prefix_embeds=None):
             lp, flag = inp
             window = attention.layer_window(cfg.attn, flag, s) \
                 if cfg.attn else None
-            x, a_, _ = _layer_train(x, lp, window, cfg, ex)
-            return (x, aux + a_), None
+            x, a_, _, st = _layer_train(x, lp, window, cfg, ex)
+            return (x, aux + a_), st
 
         body = ex.wrap_remat(body)
-        (x, aux), _ = common.layer_scan(ex, body, (x, 0.0),
-                                   (params["layers"], flags))
+        (x, aux), per_layer = common.layer_scan(ex, body, (x, aux),
+                                                (params["layers"], flags))
+        stats = obs_metrics.reduce_stacked(per_layer)
     x = common.norm(x, params["final_norm"], cfg.norm_eps, ex.backend)
-    return x, aux
+    return x, aux, stats
 
 
 def lm_loss(params, batch, cfg: ModelConfig, ex):
-    x, aux = lm_hidden(params, batch["tokens"], cfg, ex,
-                       batch.get("prefix_embeds"))
+    x, aux, stats = lm_hidden(params, batch["tokens"], cfg, ex,
+                              batch.get("prefix_embeds"))
     logits = _unembed(params, x, cfg)
     ce = common.cross_entropy(logits, batch["labels"],
                               logit_softcap=cfg.logit_softcap,
                               mask=batch.get("loss_mask"))
-    loss = ce + 0.01 * aux
-    return loss, {"ce": ce, "aux": aux}
+    loss = ce + (cfg.moe.aux_alpha if cfg.moe else 0.01) * aux
+    return loss, {"ce": ce, "aux": aux, **stats}
 
 
 def lm_prefill(params, tokens, cfg: ModelConfig, ex, prefix_embeds=None):
@@ -191,6 +226,12 @@ def lm_prefill(params, tokens, cfg: ModelConfig, ex, prefix_embeds=None):
             v = v[:, :, -clen:]
         return k, v
 
+    dense_kv = []
+    for lp in _dense_layers(params, cfg):
+        x, _, kv, _ = _layer_train(x, lp, attention.layer_window(
+            cfg.attn, True, s), cfg, ex, collect_kv=True)
+        dense_kv.append(trim(kv))
+
     if _use_period_path(cfg, ex):
         p = cfg.attn.local_global_period
         main, rest, n_full, n_rest = _split_periods(params["layers"], p,
@@ -200,8 +241,8 @@ def lm_prefill(params, tokens, cfg: ModelConfig, ex, prefix_embeds=None):
             ks, vs = [], []
             for j in range(p):
                 lp = jax.tree.map(lambda t: t[j], lp_grp)
-                x, _, kv = _layer_train(x, lp, _period_window(cfg, j),
-                                        cfg, ex, collect_kv=True)
+                x, _, kv, _ = _layer_train(x, lp, _period_window(cfg, j),
+                                           cfg, ex, collect_kv=True)
                 k, v = trim(kv)
                 ks.append(k)
                 vs.append(v)
@@ -212,8 +253,8 @@ def lm_prefill(params, tokens, cfg: ModelConfig, ex, prefix_embeds=None):
         cv = cv.reshape(n_full * p, *cv.shape[2:])
         for j in range(n_rest):
             lp = jax.tree.map(lambda t: t[j], rest)
-            x, _, kv = _layer_train(x, lp, _period_window(cfg, j), cfg, ex,
-                                    collect_kv=True)
+            x, _, kv, _ = _layer_train(x, lp, _period_window(cfg, j), cfg,
+                                       ex, collect_kv=True)
             k, v = trim(kv)
             ck = jnp.concatenate([ck, k[None]], 0)
             cv = jnp.concatenate([cv, v[None]], 0)
@@ -225,13 +266,16 @@ def lm_prefill(params, tokens, cfg: ModelConfig, ex, prefix_embeds=None):
             lp, flag = inp
             window = attention.layer_window(cfg.attn, flag, s) \
                 if cfg.attn else None
-            x, a, kv = _layer_train(x, lp, window, cfg, ex,
-                                    collect_kv=True)
+            x, a, kv, _ = _layer_train(x, lp, window, cfg, ex,
+                                       collect_kv=True)
             k, v = trim(kv)
             return (x, aux + a), (k, v)
 
         (x, _), (ck, cv) = common.layer_scan(ex, body, (x, 0.0),
                                         (params["layers"], flags))
+    if dense_kv:
+        ck = jnp.concatenate([jnp.stack([k for k, _ in dense_kv]), ck])
+        cv = jnp.concatenate([jnp.stack([v for _, v in dense_kv]), cv])
     x = common.norm(x, params["final_norm"], cfg.norm_eps, ex.backend)
     logits = _unembed(params, x[:, -1:], cfg)[:, 0]
     return logits, {"k": ck, "v": cv}
@@ -241,8 +285,9 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype):
     """Zeroed KV cache sized for ``seq_len`` total positions."""
     a = cfg.attn
     clen, _ = _cache_len(cfg, seq_len)
-    shape = (cfg.n_layers, batch, a.n_kv_heads, clen, a.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    shape = (cfg.n_layers, batch, a.n_kv_heads, clen)
+    return {"k": jnp.zeros(shape + (a.head_dim,), dtype),
+            "v": jnp.zeros(shape + (a.v_dim,), dtype)}
 
 
 def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, ex):
@@ -255,22 +300,36 @@ def lm_decode_step(params, cache, tokens, pos, cfg: ModelConfig, ex):
     rolling = a_cfg.window if (a_cfg.window and
                                a_cfg.local_global_period == 0) else None
 
-    def body(x, inp):
-        lp, flag, ck, cv = inp
+    def layer(x, lp, flag, ck, cv):
         h = common.norm(x, lp["ln1"], cfg.norm_eps, ex.backend)
         att, ck, cv = attention.attn_decode(
             lp["attn"], h, ck, cv, pos, a_cfg, is_global=flag,
             norm_eps=cfg.norm_eps, ex=ex, rolling_window=rolling)
         x = x + att
         h = common.norm(x, lp["ln2"], cfg.norm_eps, ex.backend)
-        if cfg.moe is not None:
-            m, _ = moe.moe_apply(lp["moe"], h, cfg.moe, ex)
+        if "moe" in lp:
+            m, _, _ = moe.moe_apply(lp["moe"], h, cfg.moe, ex)
         else:
             m = common.mlp_apply(lp["mlp"], h, cfg.gated_mlp)
         return x + m, (ck, cv)
 
-    x, (ck, cv) = common.layer_scan(ex, 
-        body, x, (params["layers"], flags, cache["k"], cache["v"]))
+    n_dense = cfg.first_k_dense
+    ck, cv = cache["k"], cache["v"]
+    dense_kv = []
+    for i, lp in enumerate(_dense_layers(params, cfg)):
+        x, kv = layer(x, lp, True, ck[i], cv[i])
+        dense_kv.append(kv)
+    if n_dense:
+        ck, cv = ck[n_dense:], cv[n_dense:]
+
+    def body(x, inp):
+        return layer(x, *inp)
+
+    x, (ck, cv) = common.layer_scan(ex, body, x,
+                                    (params["layers"], flags, ck, cv))
+    if dense_kv:
+        ck = jnp.concatenate([jnp.stack([k for k, _ in dense_kv]), ck])
+        cv = jnp.concatenate([jnp.stack([v for _, v in dense_kv]), cv])
     x = common.norm(x, params["final_norm"], cfg.norm_eps, ex.backend)
     logits = _unembed(params, x[:, 0], cfg)
     if cfg.logit_softcap:

@@ -54,6 +54,8 @@ KNOWN_COUNTERS = frozenset({
     "dse.cache.fallback_rows",
     "dse.cache.hits",
     "dse.cache.sim",
+    "moe.gmm_pad_rows",
+    "moe.routed_rows",
     "outer.event_replayed",
     "outer.variant_cache.hits",
     "outer.variants_evaluated",
@@ -64,6 +66,7 @@ KNOWN_COUNTERS = frozenset({
     "profile.measurements",
 })
 KNOWN_GAUGES = frozenset({
+    "moe.load_max_over_mean",
     "profile.achieved_gbs",
     "profile.achieved_tflops",
 })
@@ -131,6 +134,14 @@ def gauge(name: str, value: float) -> None:
     tr = _trace.current_tracer()
     if tr is not None:
         tr.sample(name, float(value))
+
+
+def reduce_stacked(values: dict) -> dict:
+    """Per-call values stacked on a leading axis (layers, micro-batches)
+    -> one value each: declared gauges averaged, everything else summed
+    as a count.  Works on any array type with ``mean``/``sum``."""
+    return {k: v.mean(0) if k in KNOWN_GAUGES else v.sum(0)
+            for k, v in values.items()}
 
 
 @contextmanager

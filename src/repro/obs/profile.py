@@ -116,11 +116,7 @@ def _moe_case(t: int, n: int):
     # equal groups; every M on the grid is a multiple of 4 * 8, so each
     # group is whole token blocks of the Pallas kernel
     block_t = min(128, t // e)
-    if ops.default_backend() == "pallas":
-        groups = jnp.repeat(jnp.arange(e, dtype=jnp.int32),
-                            t // e // block_t)
-    else:           # the xla/ref path takes concrete group sizes
-        groups = [t // e] * e
+    groups = [t // e] * e
     fn = jax.jit(lambda x_, w_: ops.moe_gmm(x_, w_, groups,
                                             block_t=block_t))
     flops = 2.0 * t * k * n

@@ -63,7 +63,7 @@ def param_specs(cfg: ModelConfig, params_shape: Dict[str, Any], mesh):
         names = [getattr(p, "key", getattr(p, "name", "")) for p in path]
         name = names[-1]
         stacked = "layers" in names or "enc_layers" in names \
-            or "dec_layers" in names
+            or "dec_layers" in names or "dense_layers" in names
         pre = (None,) if stacked else ()
         nd = len(leaf.shape)
 
@@ -76,7 +76,7 @@ def param_specs(cfg: ModelConfig, params_shape: Dict[str, Any], mesh):
             return P(*spec)
         if name == "pos_embed":
             return P()
-        if name in ("wq", "wk", "wv", "in_proj"):
+        if name in ("wq", "wk", "wv", "in_proj", "wkv_b"):
             return P(*pre, fsdp, tp)
         if name in ("wo", "out_proj"):
             return P(*pre, tp, fsdp)

@@ -27,6 +27,18 @@ from typing import Callable, Optional
 
 import jax
 
+from repro.obs import metrics as obs_metrics
+
+
+def _record(metrics) -> None:
+    """The step's declared metrics into ``repro.obs``: counters added,
+    gauges set; the rest (loss, learning rate, ...) stay the caller's."""
+    for name, value in metrics.items():
+        if name in obs_metrics.KNOWN_COUNTERS:
+            obs_metrics.inc(name, float(value))
+        elif name in obs_metrics.KNOWN_GAUGES:
+            obs_metrics.gauge(name, float(value))
+
 
 class Watchdog:
     def __init__(self, deadline_s: float = 1800.0):
@@ -117,6 +129,7 @@ class FaultTolerantLoop:
                     self._ewma = 0.9 * self._ewma + 0.1 * dt
                 step += 1
                 self.pipeline.state = self.pipeline.state.advance()
+                _record(metrics)
                 if on_metrics is not None:
                     on_metrics(step, metrics, dt)
                 if step % self.checkpoint_every == 0:

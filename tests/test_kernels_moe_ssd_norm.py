@@ -32,6 +32,25 @@ def test_moe_gmm(sizes, bt, dtype):
                                np.asarray(r, np.float32), rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("n_live", [0, 3, 5])
+def test_moe_gmm_computes_only_the_live_blocks(n_live):
+    """Rows sized for more blocks than hold rows: the first ``n_live``
+    blocks match the oracle, tiled over K and N as well (the blocks past
+    them fetch nothing and are left for the caller to mask)."""
+    sizes = [16, 0, 24, 0]                   # 5 blocks of 8 rows
+    e, k, n, t = len(sizes), 64, 96, 64      # 8 blocks allocated
+    x = jax.random.normal(jax.random.PRNGKey(0), (t, k))
+    w = jax.random.normal(jax.random.PRNGKey(1), (e, k, n)) * 0.1
+    gids = np.repeat(np.arange(e), np.asarray(sizes) // 8)
+    gids = np.concatenate([gids, np.full(8 - len(gids), gids[-1])])
+    out = moe_gmm(x, w, jnp.asarray(gids, jnp.int32), n_live, block_t=8,
+                  block_n=32, block_k=32, interpret=True)
+    r = ref.moe_gmm_ref(x, w, np.asarray(sizes))
+    live = 8 * n_live
+    np.testing.assert_allclose(np.asarray(out[:live]), np.asarray(r[:live]),
+                               rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("bb,s,h,p,g,n,chunk", [
     (1, 32, 2, 8, 1, 16, 8),
     (2, 64, 4, 16, 2, 16, 16),

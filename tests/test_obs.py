@@ -186,6 +186,16 @@ def test_metrics_scope_folds_into_parent():
     assert metrics.root().counters["t.x"] == root_before + 5
 
 
+def test_reduce_stacked_sums_counts_and_averages_gauges():
+    import numpy as np
+    stacked = {"moe.routed_rows": np.array([3.0, 5.0]),
+               "moe.load_max_over_mean": np.array([1.0, 2.0]),
+               "t.undeclared": np.array([1.0, 1.0])}
+    assert metrics.reduce_stacked(stacked) == {
+        "moe.routed_rows": 8.0, "moe.load_max_over_mean": 1.5,
+        "t.undeclared": 2.0}
+
+
 def test_metrics_snapshot_schema():
     m = metrics.Metrics()
     m.inc("a.b", 4)

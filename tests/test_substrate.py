@@ -154,6 +154,26 @@ def test_crash_restore_bitwise_identical(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_loop_records_the_steps_declared_metrics(tmp_path):
+    """Declared counters add up over the steps, declared gauges keep the
+    last step's value; the step's other metrics are not recorded."""
+    from repro.obs import metrics as obs_metrics
+    values = iter([1.5, 2.5])
+
+    def step(state, batch):
+        return state, {"loss": jnp.float32(2.0),
+                       "moe.routed_rows": jnp.float32(3.0),
+                       "moe.load_max_over_mean": jnp.float32(next(values))}
+
+    mgr = CheckpointManager(tmp_path / "ck", async_write=False)
+    loop = FaultTolerantLoop(step, mgr, DataPipeline(CFG, SHAPE, seed=1),
+                             checkpoint_every=100)
+    with obs_metrics.scope() as m:
+        loop.run(_fresh(), 2)
+    assert m.counters == {"moe.routed_rows": 6.0}
+    assert m.gauges == {"moe.load_max_over_mean": 2.5}
+
+
 def test_grad_accumulation_matches_large_batch():
     """accum=2 over batch 4 == one step over the same 4 sequences."""
     step1 = jax.jit(make_train_step(CFG, EX, base_lr=1e-4))

@@ -57,14 +57,38 @@ def _compile_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("arch", ["tinyllama_1_1b", "qwen3_moe_235b_a22b"])
+@pytest.mark.parametrize("arch", ["tinyllama_1_1b", "qwen3_moe_235b_a22b",
+                                  "deepseek_v2_lite"])
 def test_flash_attention_fwd_compiles(one_chip, arch):
     a = get_config(arch).attn
     q = _spec(one_chip, (1, a.n_heads, 2048, a.head_dim))
-    kv = _spec(one_chip, (1, a.n_kv_heads, 2048, a.head_dim))
+    k = _spec(one_chip, (1, a.n_kv_heads, 2048, a.head_dim))
+    v = _spec(one_chip, (1, a.n_kv_heads, 2048, a.v_dim))
     text = _compile_text(lambda q_, k_, v_: flash_attention_fwd(q_, k_, v_),
-                         q, kv, kv)
+                         q, k, v)
     assert "tpu_custom_call" in text
+
+
+def test_moe_gmm_share_compiles_with_its_vjp(one_chip):
+    """One chip's share of DeepSeek-V2-Lite's experts (8 of 64) at a
+    micro-batch of 16,384 tokens, rows sized for the worst case: the
+    Pallas forward and XLA's grouped matmuls for the backward."""
+    import dataclasses
+
+    from repro.models.moe import share_rows
+    cfg = get_config("deepseek_v2_lite")
+    m = dataclasses.replace(cfg.moe, experts_held=8)
+    rows = share_rows(16384, m)
+
+    def f(x, w, g):
+        return jax.value_and_grad(lambda x_, w_: jnp.sum(ops.moe_gmm(
+            x_, w_, g, backend="pallas").astype(jnp.float32)), (0, 1))(x, w)
+
+    text = _compile_text(
+        f, _spec(one_chip, (rows, cfg.d_model), jnp.bfloat16),
+        _spec(one_chip, (8, cfg.d_model, m.d_ff_expert), jnp.bfloat16),
+        _spec(one_chip, (8,), jnp.int32))
+    assert "%moe_gmm" in text and "ragged-dot" in text
 
 
 def test_ssd_scan_compiles_mamba2(one_chip):
