@@ -326,7 +326,7 @@ def _flash_attention_fwd_rule(q, k, v, window, causal, softcap, scale,
                               block, backend):
     if backend == "pallas":
         o, lse = _fa_pallas_on_mesh(q, k, v, window, causal, softcap,
-                                    scale, block)
+                                    scale)
     elif backend == "xla_blocked" and _static_window(window):
         o, lse = _fa_fwd_xla_blocked(q, k, v, window, causal, softcap,
                                      scale, block)
@@ -335,9 +335,10 @@ def _flash_attention_fwd_rule(q, k, v, window, causal, softcap, scale,
     return o, (q, k, v, o, lse, window)
 
 
-def _fa_pallas_on_mesh(q, k, v, window, causal, softcap, scale, block):
+def _fa_pallas_on_mesh(q, k, v, window, causal, softcap, scale):
+    # the kernel's tiles come from the shapes (``fwd_plan``), not ``block``
     call = functools.partial(_fa_pallas, causal=causal, softcap=softcap,
-                             scale=scale, block_q=block, block_k=block)
+                             scale=scale)
     mesh = _kernel_mesh()
     if mesh is None:
         return call(q, k, v, window)

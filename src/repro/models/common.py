@@ -18,6 +18,8 @@ class ExecConfig:
     # activation checkpointing policy applied to each scanned layer:
     #   'none' | 'full' | 'dots'
     remat: str = "none"
+    # tile of the XLA attention paths (forward and backward); the Pallas
+    # forward sizes its own tiles from the shapes (``fwd_plan``)
     attn_block: int = 128
     ssd_chunk: int = 128
     backend: Optional[str] = None      # kernel backend override

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention import (_VMEM_BUDGET, flash_attention_fwd,
+                                          fwd_plan)
 
 
 def _mk(b, hq, hkv, s, d, dtype):
@@ -41,6 +42,102 @@ def test_pallas_interpret_vs_ref(b, hq, hkv, s, d, win, cap, causal, dtype):
                                np.asarray(r, np.float32),
                                rtol=tol, atol=tol)
     assert bool(jnp.isfinite(lse).all())
+
+
+PLAN_SWEEP = [
+    # b, hq, hkv, s, d, dv, window, dynamic, softcap, causal
+    (1, 4, 2, 1024, 64, 64, None, False, 0.0, True),     # GQA 2:1
+    (1, 2, 2, 1024, 192, 128, None, False, 0.0, True),   # MLA: D 192, Dv 128
+    (1, 2, 1, 1024, 64, 64, 700, False, 0.0, True),      # static window
+    (1, 2, 1, 1024, 64, 64, 700, True, 0.0, True),       # dynamic window
+    (1, 2, 2, 512, 64, 64, None, False, 30.0, True),     # softcap
+    (1, 4, 2, 1024, 64, 64, None, False, 0.0, False),    # non-causal
+]
+
+
+@pytest.mark.parametrize("b,hq,hkv,s,d,dv,win,dynamic,cap,causal",
+                         PLAN_SWEEP)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pallas_plan_tiles_vs_ref(b, hq, hkv, s, d, dv, win, dynamic, cap,
+                                  causal, dtype):
+    """The forward with the plan's own tiles (interpreted), against the
+    dense oracle."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (b, hq, s, d), dtype)
+    k = jax.random.normal(ks[1], (b, hkv, s, d), dtype)
+    v = jax.random.normal(ks[2], (b, hkv, s, dv), dtype)
+
+    def fwd(w):
+        return flash_attention_fwd(q, k, v, w, causal=causal, softcap=cap,
+                                   interpret=True)
+
+    o, lse = jax.jit(fwd)(jnp.int32(win)) if dynamic else fwd(win)
+    r = ref.attention_ref(q, k, v, causal=causal, window=win, softcap=cap)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert o.dtype == dtype and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(r, np.float32),
+                               rtol=tol, atol=tol)
+    assert bool(jnp.isfinite(lse).all())
+
+
+@pytest.mark.parametrize("dynamic", [False, True])
+def test_pallas_window_skips_whole_blocks(dynamic):
+    """A window of 100 over 64-row tiles leaves whole blocks out: the
+    static table drops them, the dynamic window skips them in the
+    kernel; both equal the dense oracle."""
+    q, k, v = _mk(1, 2, 1, 512, 32, jnp.float32)
+
+    def fwd(w):
+        return flash_attention_fwd(q, k, v, w, block_q=64, block_k=64,
+                                   interpret=True)[0]
+
+    o = jax.jit(fwd)(jnp.int32(100)) if dynamic else fwd(100)
+    assert fwd_plan(512, 32, 32, jnp.float32, None if dynamic else 100,
+                    block_q=64, block_k=64).n_live == (36 if dynamic else 21)
+    r = ref.attention_ref(q, k, v, window=100)
+    np.testing.assert_allclose(o, r, rtol=2e-5, atol=2e-5)
+
+
+def _blocks_touched(s, bq, bk, causal, window):
+    """Blocks holding at least one (query, key) pair the mask keeps,
+    counted pair by pair."""
+    rows = np.arange(s)[:, None]
+    cols = np.arange(s)[None, :]
+    keep = np.ones((s, s), bool)
+    if causal:
+        keep &= cols <= rows
+    if window is not None:
+        keep &= rows - cols < window
+    return int(keep.reshape(s // bq, bq, s // bk, bk).any(axis=(1, 3)).sum())
+
+
+@pytest.mark.parametrize("s,d,dv,dtype,win,causal", [
+    (512, 64, 64, jnp.float32, None, True),
+    (1024, 128, 128, jnp.bfloat16, None, True),
+    (1024, 192, 128, jnp.bfloat16, 700, True),
+    (4096, 128, 128, jnp.bfloat16, None, True),
+    (4096, 192, 128, jnp.bfloat16, 1000, True),
+    (4096, 128, 128, jnp.float32, None, False),
+    (1536, 256, 256, jnp.float32, None, True),
+])
+def test_fwd_plan(s, d, dv, dtype, win, causal):
+    plan = fwd_plan(s, d, dv, dtype, win, causal=causal)
+    bq, bk = plan.block_q, plan.block_k
+    assert s % bq == 0 and s % bk == 0
+    assert plan.vmem_bytes <= _VMEM_BUDGET
+    assert plan.n_live == _blocks_touched(s, bq, bk, causal, win)
+    qi, ki = plan.steps[:, 0], plan.steps[:, 1]
+    # a q tile's steps are consecutive, k ascending within it
+    assert (np.diff(qi) >= 0).all()
+    assert (np.diff(ki)[np.diff(qi) == 0] > 0).all()
+    if s == 4096 and causal and win is None:
+        # the causal triangle, n (n + 1) / 2 of n^2 steps: 10 of 16 at
+        # 1024-row tiles (62.5%), 36 of 64 (56%) at 512
+        n = s // bq
+        assert bq == bk and plan.n_live == n * (n + 1) // 2
+        assert fwd_plan(s, d, dv, dtype, block_q=512,
+                        block_k=512).n_live < 0.6 * 64
 
 
 @pytest.mark.parametrize("b,hq,hkv,s,d,win,cap,causal", SWEEP)

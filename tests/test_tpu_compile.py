@@ -69,6 +69,22 @@ def test_flash_attention_fwd_compiles(one_chip, arch):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("b,hq,hkv,s,d,dv", [
+    (2, 16, 8, 4096, 128, 128),      # InternLM2-1.8B, 2 x 4,096 tokens
+    (4, 16, 16, 4096, 192, 128),     # DeepSeek-V2-Lite MLA, 4 x 4,096
+])
+def test_flash_attention_fwd_compiles_at_train_shapes(one_chip, b, hq, hkv,
+                                                      s, d, dv):
+    """The forward with the plan's tiles in bfloat16 at the train cells'
+    shapes: its VMEM fits, and the kernel keeps its name."""
+    bf = jnp.bfloat16
+    text = _compile_text(lambda q_, k_, v_: flash_attention_fwd(q_, k_, v_),
+                         _spec(one_chip, (b, hq, s, d), bf),
+                         _spec(one_chip, (b, hkv, s, d), bf),
+                         _spec(one_chip, (b, hkv, s, dv), bf))
+    assert "%flash_attention_fwd" in text
+
+
 def test_moe_gmm_share_compiles_with_its_vjp(one_chip):
     """One chip's share of DeepSeek-V2-Lite's experts (8 of 64) at a
     micro-batch of 16,384 tokens, rows sized for the worst case: the
